@@ -6,7 +6,7 @@ three ways at each ladder mix:
 
 * ``device_kv="dense"`` — decode attends a bf16 cache; the ladder's
   bandwidth saving is accounting-only;
-* ``device_kv="bitplane"`` + ``decode_kernel="rung"`` — packed uint8
+* ``device_kv="bitplane"`` + ``decode_kernel="rung"`` — packed int32
   planes, one partial-plane Pallas launch per rung in the ladder's static
   rung set, partials merged outside the kernel;
 * ``device_kv="bitplane"`` + ``decode_kernel="fused"`` — ONE Pallas launch

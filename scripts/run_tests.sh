@@ -5,4 +5,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# the suite runs on the CPU (Pallas kernels in interpret mode) and must never
+# take an attached TPU; chip runs go through chip_smoke.py
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 exec python -m pytest -q "$@"

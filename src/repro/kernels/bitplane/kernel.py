@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 
 DEFAULT_BLOCK_BYTES = 4096  # one VMEM plane-block == the paper's 4 KB block
 
@@ -51,7 +53,7 @@ def _unpack_kernel(planes_ref, u_ref, *, bits: int, keep: int):
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_bytes", "interpret"))
 def pack(u: jnp.ndarray, bits: int, block_bytes: int = DEFAULT_BLOCK_BYTES,
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: bool | None = None) -> jnp.ndarray:
     """(m,) uint32 (m % (8*block_bytes) == 0) -> (bits, m//8) uint8."""
     m = u.shape[0]
     mbytes = m // 8
@@ -63,7 +65,7 @@ def pack(u: jnp.ndarray, bits: int, block_bytes: int = DEFAULT_BLOCK_BYTES,
         in_specs=[pl.BlockSpec((block_bytes, 8), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bits, block_bytes), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((bits, mbytes), jnp.uint8),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(u.reshape(mbytes, 8))
 
 
@@ -71,7 +73,7 @@ def pack(u: jnp.ndarray, bits: int, block_bytes: int = DEFAULT_BLOCK_BYTES,
     jax.jit, static_argnames=("bits", "keep", "block_bytes", "interpret")
 )
 def unpack(planes: jnp.ndarray, bits: int, keep: int | None = None,
-           block_bytes: int = DEFAULT_BLOCK_BYTES, interpret: bool = True) -> jnp.ndarray:
+           block_bytes: int = DEFAULT_BLOCK_BYTES, interpret: bool | None = None) -> jnp.ndarray:
     """(bits, m//8) uint8 -> (m,) uint32, fetching only the top ``keep``
     planes from memory (BlockSpec block height = keep)."""
     keep = bits if keep is None else keep
@@ -86,6 +88,6 @@ def unpack(planes: jnp.ndarray, bits: int, keep: int | None = None,
         in_specs=[pl.BlockSpec((keep, block_bytes), lambda i: (0, i))],
         out_specs=pl.BlockSpec((block_bytes, 8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mbytes, 8), jnp.uint32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(planes)
     return out.reshape(mbytes * 8)

@@ -20,7 +20,7 @@ def _pad_values(u: jnp.ndarray, block_values: int) -> tuple:
 
 
 def pack(x: jnp.ndarray, spec: FloatSpec, block_bytes: int = K.DEFAULT_BLOCK_BYTES,
-         interpret: bool = True) -> tuple:
+         interpret: bool | None = None) -> tuple:
     """Tensor -> (planes (bits, padded//8) uint8, n_values).
 
     One plane row of ``block_bytes`` bytes corresponds to 8·block_bytes
@@ -32,7 +32,7 @@ def pack(x: jnp.ndarray, spec: FloatSpec, block_bytes: int = K.DEFAULT_BLOCK_BYT
 
 
 def unpack(planes: jnp.ndarray, spec: FloatSpec, shape, keep: int | None = None,
-           block_bytes: int = K.DEFAULT_BLOCK_BYTES, interpret: bool = True) -> jnp.ndarray:
+           block_bytes: int = K.DEFAULT_BLOCK_BYTES, interpret: bool | None = None) -> jnp.ndarray:
     """Planes -> tensor of ``shape`` (top-``keep``-plane truncation applied
     when keep < bits — the memory-side meaning of FP-k)."""
     import numpy as np

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 
 def _kernel(x_ref, p_ref, o_ref, *, keep: int, bits: int):
     """x (bm, bk) bf16; p (keep, bk, bn//8) uint8; o (bm, bn) f32."""
@@ -54,7 +56,7 @@ def bitplane_matmul(
     bm: int = 128,
     bk: int = 512,
     bn: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """x (M, K) bf16 × planes (bits, K, N//8) -> (M, N) f32.
 
@@ -80,5 +82,5 @@ def bitplane_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(x, planes)

@@ -19,7 +19,7 @@ def pack_weights(w: jnp.ndarray, bits: int = 16) -> jnp.ndarray:
 
 
 def bitplane_matmul(x: jnp.ndarray, planes: jnp.ndarray, keep: int = 16,
-                    bits: int = 16, interpret: bool = True, **blocks) -> jnp.ndarray:
+                    bits: int = 16, interpret: bool | None = None, **blocks) -> jnp.ndarray:
     """x (M, K) bf16 × plane-packed weights -> (M, N) f32.
 
     M is padded to the 128-row MXU tile if needed."""

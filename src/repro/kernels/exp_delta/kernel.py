@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 
 def _encode_kernel(u_ref, enc_ref, base_ref, *, man_bits: int, exp_mask: int):
     u = u_ref[...].astype(jnp.uint32)  # (bc, G)
@@ -42,7 +44,7 @@ def _decode_kernel(enc_ref, base_ref, u_ref, *, man_bits: int, exp_mask: int):
     jax.jit, static_argnames=("man_bits", "exp_mask", "block_c", "interpret")
 )
 def encode(u: jnp.ndarray, man_bits: int, exp_mask: int, block_c: int = 256,
-           interpret: bool = True):
+           interpret: bool | None = None):
     """u: (C, G) uint32 (C % block_c == 0) -> (encoded (C, G), base (C,))."""
     c, g = u.shape
     assert c % block_c == 0, (c, block_c)
@@ -59,7 +61,7 @@ def encode(u: jnp.ndarray, man_bits: int, exp_mask: int, block_c: int = 256,
             jax.ShapeDtypeStruct((c, g), jnp.uint32),
             jax.ShapeDtypeStruct((c,), jnp.uint32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(u)
 
 
@@ -67,7 +69,7 @@ def encode(u: jnp.ndarray, man_bits: int, exp_mask: int, block_c: int = 256,
     jax.jit, static_argnames=("man_bits", "exp_mask", "block_c", "interpret")
 )
 def decode(encoded: jnp.ndarray, base: jnp.ndarray, man_bits: int, exp_mask: int,
-           block_c: int = 256, interpret: bool = True):
+           block_c: int = 256, interpret: bool | None = None):
     c, g = encoded.shape
     assert c % block_c == 0
     grid = (c // block_c,)
@@ -80,5 +82,5 @@ def decode(encoded: jnp.ndarray, base: jnp.ndarray, man_bits: int, exp_mask: int
         ],
         out_specs=pl.BlockSpec((block_c, g), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, g), jnp.uint32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(encoded, base)

@@ -21,7 +21,7 @@ def _pad_channels(u: jnp.ndarray, block_c: int):
 
 
 def encode(u: jnp.ndarray, spec: FloatSpec, block_c: int = 256,
-           interpret: bool = True):
+           interpret: bool | None = None):
     """u: (C, G) raw uint view (any uint dtype). Returns (encoded, base)
     in the input dtype / uint8 base."""
     if spec.exp_bits == 0:
@@ -33,7 +33,7 @@ def encode(u: jnp.ndarray, spec: FloatSpec, block_c: int = 256,
 
 
 def decode(encoded: jnp.ndarray, base: jnp.ndarray, spec: FloatSpec,
-           block_c: int = 256, interpret: bool = True):
+           block_c: int = 256, interpret: bool | None = None):
     if spec.exp_bits == 0:
         return encoded
     orig_dtype = encoded.dtype

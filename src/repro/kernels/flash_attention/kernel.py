@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -86,7 +88,7 @@ def flash_attention(
     kv_len: int | None = None,
     bq: int = 512,
     bkv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q (B, Sq, Hp, hd) bf16; k/v (B, Skv, Hkv, hd); Hp % Hkv == 0.
 
@@ -121,5 +123,5 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)

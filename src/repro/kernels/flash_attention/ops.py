@@ -10,7 +10,7 @@ from repro.kernels.flash_attention import kernel as K
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, bq=512, bkv=512,
-                    interpret=True):
+                    interpret=None):
     b, sq, hp, hd = q.shape
     skv = k.shape[1]
     scale_hd = hd  # real head_dim defines the softmax scale

@@ -1,6 +1,5 @@
 from repro.kernels.paged_attention.ops import (  # noqa: F401
     batched_ladder_paged_attention,
-    default_interpret,
     ladder_paged_attention,
     pack_kv_planes,
 )

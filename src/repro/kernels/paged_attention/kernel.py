@@ -1,38 +1,43 @@
-"""Paged decode attention over a bit-plane-packed KV cache (paper Fig. 5/6
-device path).
+"""Paged decode attention over the bit-plane KV layout (paper Fig. 5/6
+device path; layout in ``ref.py``).
 
 Two kernels serve the ladder:
 
 * ``paged_attention_rung`` — one invocation per precision rung (a page set
   at a fixed ``keep``); the ops wrapper composes rungs of the Quest ladder
-  (§II.C) and merges their online-softmax partials host-side.  One compile
-  per rung-set member.
-* ``paged_attention_fused`` (ISSUE 6) — ONE invocation walks the per-page
-  plane map inline: each tile's page keeps ride in SMEM, every page's
-  planes [0, keep) arrive via predicated async copies from the packed
-  planes left in ``ANY`` memory space, and planes keep..15 are never
-  touched.  No per-rung launch loop, no unnormalised-partials merge — one
-  compile per model config, whatever the ladder's rung set.
+  (§II.C) and merges their online-softmax partials.  One compile per
+  rung-set member.
+* ``paged_attention_fused`` — ONE invocation walks the per-page plane map
+  inline: the map arrives by scalar prefetch, every page's planes
+  [0, keep) arrive via predicated async copies from the planes left in
+  ``ANY`` memory space, and planes keep..15 are never touched.  No per-rung
+  launch loop, no partials merge — one compile per model config, whatever
+  the ladder's rung set.
 
-HBM traffic per page = keep/16 of the bf16 KV bytes — the "memory
+HBM traffic per page = keep/16 of the padded plane units — the "memory
 bandwidth scales proportionally with dynamic quantization" claim, enforced
 structurally (rung: the BlockSpec maps only ``keep`` planes; fused: the
 plane DMA loop is predicated on the page's keep).
 
-Grid (B, Hkv, S/bs), S innermost; scratch carries m/l/acc.  The rung
-kernel emits UNNORMALISED partials (o·l, m, l) so rungs merge exactly; the
-fused kernel normalises in its finish block (nothing left to merge).
+Grid (B, S/bs), S innermost; scratch carries m/l/acc.  Each program serves
+every KV head of its slot: q arrives spread over the element lanes of its
+own KV head (zeros elsewhere, see ``ops._spread_q``), so one matmul per
+element half scores all heads at once.  The rung kernel emits UNNORMALISED
+partials (o·l, m, l) so rungs merge exactly; the fused kernel normalises in
+its finish block.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
+from repro.kernels.paged_attention.ref import PAGE
 
 NEG_INF = -1e30
 
@@ -43,74 +48,90 @@ NEG_INF = -1e30
 TRACE_COUNTS = {"rung": 0, "fused": 0}
 
 
-def default_interpret() -> bool:
-    """Pallas interpret default: interpreter off accelerators, compiled on
-    TPU.  The old hardcoded ``interpret=True`` silently interpreted on TPU
-    runs, throwing away the Mosaic kernel; ``None`` arguments now resolve
-    here.  ``REPRO_PALLAS_INTERPRET=0|1`` overrides (debugging a TPU run in
-    interpret mode, or forcing compilation in a CPU smoke test)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "cpu"
+def _unpack_tile(read_plane, n_planes: int, ppt: int, w: int, bits: int,
+                 live=None):
+    """Assemble a tile's KV from its plane units: ``read_plane(i)`` gives
+    plane i as (ppt, w) int32 words; returns the two element halves, each
+    (ppt*PAGE, w) bf16 with rows in token order.  ``live`` (ppt, w) int32
+    zeroes plane i of every page whose keep is <= i (its buffer rows may
+    hold an earlier tile's bytes — the DMA loop never refreshed them)."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (ppt, PAGE, w), 1)
+    t = t.reshape(ppt * PAGE, w)
+    lo = jnp.zeros((ppt * PAGE, w), jnp.int32)
+    hi = jnp.zeros((ppt * PAGE, w), jnp.int32)
+    for i in range(n_planes):
+        x = read_plane(i)
+        if live is not None:
+            x = jnp.where(i < live, x, 0)
+        x = jnp.broadcast_to(x[:, None, :], (ppt, PAGE, w))
+        x = x.reshape(ppt * PAGE, w)
+        lo = lo | (((x >> t) & 1) << (bits - 1 - i))
+        hi = hi | (((x >> (t + PAGE)) & 1) << (bits - 1 - i))
+    # a bf16 is the top half of an f32 with the same bits
+    return [jax.lax.bitcast_convert_type(u << 16, jnp.float32)
+            .astype(jnp.bfloat16) for u in (lo, hi)]
 
 
-def _unpack_tile(p, keep: int, bits: int):
-    """(keep, bs, hd8) uint8 planes -> (bs, hd) bf16."""
-    byte_w = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 1, 8), 3)
-    bm8 = (p.astype(jnp.uint32)[..., None] >> (7 - byte_w)) & 1
-    plane_w = jax.lax.broadcasted_iota(jnp.uint32, (keep, 1, 1, 1), 0)
-    u = (bm8 << ((bits - 1) - plane_w)).sum(axis=0)  # (bs, hd8, 8)
-    u16 = u.reshape(u.shape[0], -1).astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(u16, jnp.bfloat16)
+def _attend(q_ref, mask_ref, k, v, m_scr, l_scr, acc_scr, *, first,
+            scale: float):
+    """One tile of the online softmax over all heads' (hq, bs) scores."""
 
-
-def _kernel(q_ref, kp_ref, vp_ref, mask_ref, o_ref, m_ref, l_ref,
-            m_scr, l_scr, acc_scr, *, keep: int, bits: int, scale: float,
-            n_s: int):
-    TRACE_COUNTS["rung"] += 1
-    j = pl.program_id(2)
-    q = q_ref[...].reshape(q_ref.shape[2], q_ref.shape[3])  # (rep, hd)
-    # (keep, 1, bs, 1, hd8) -> (keep, bs, hd8)
-    kp = kp_ref[...].reshape(kp_ref.shape[0], kp_ref.shape[2], kp_ref.shape[4])
-    vp = vp_ref[...].reshape(vp_ref.shape[0], vp_ref.shape[2], vp_ref.shape[4])
-    k = _unpack_tile(kp, keep, bits)
-    v = _unpack_tile(vp, keep, bits)
-
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (rep, bs)
-    ok = mask_ref[...].reshape(1, -1) > 0
-    s = jnp.where(ok, s, NEG_INF)
+    dims = (((1,), (1,)), ((), ()))
+    s = sum(jax.lax.dot_general(q_ref[0, c], k[c], dims,
+                                preferred_element_type=jnp.float32)
+            for c in range(2)) * scale  # (hq, bs)
+    s = jnp.where(mask_ref[0, 0] > 0, s, NEG_INF)
 
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    m_prev = m_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = l_scr[:, 0] * corr + p.sum(axis=1)
-    acc = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
-    acc_scr[...] = acc
+    p = jnp.exp(s - m_new)
+    l_new = l_scr[:, 0:1] * corr + p.sum(axis=1, keepdims=True)
+    for c in range(2):
+        acc_scr[c] = acc_scr[c] * corr + jax.lax.dot_general(
+            p.astype(v[c].dtype), v[c], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _softmax_scratch(hq: int, w: int) -> list:
+    """m, l (lane-broadcast) and the two element-half accumulators."""
+    return [
+        pltpu.VMEM((hq, 128), jnp.float32),
+        pltpu.VMEM((hq, 128), jnp.float32),
+        pltpu.VMEM((2, hq, w), jnp.float32),
+    ]
+
+
+def _rung_kernel(q_ref, kp_ref, vp_ref, mask_ref, o_ref, m_ref, l_ref,
+                 m_scr, l_scr, acc_scr, *, keep: int, bits: int,
+                 scale: float, n_s: int):
+    TRACE_COUNTS["rung"] += 1
+    j = pl.program_id(1)
+    ppt, w = kp_ref.shape[2], kp_ref.shape[4]
+    k = _unpack_tile(lambda i: kp_ref[i, 0, :, 0, :], keep, ppt, w, bits)
+    v = _unpack_tile(lambda i: vp_ref[i, 0, :, 0, :], keep, ppt, w, bits)
+    _attend(q_ref, mask_ref, k, v, m_scr, l_scr, acc_scr, first=j == 0,
+            scale=scale)
 
     @pl.when(j == n_s - 1)
     def _finish():
-        o_ref[...] = acc_scr[...].reshape(o_ref.shape)  # unnormalised (o·l)
-        m_ref[...] = m_scr[:, :1].reshape(m_ref.shape)
-        l_ref[...] = l_scr[:, :1].reshape(l_ref.shape)
+        for c in range(2):
+            o_ref[0, c] = acc_scr[c]  # unnormalised (o·l)
+        m_ref[0] = m_scr[:, 0:1]
+        l_ref[0] = l_scr[:, 0:1]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("keep", "bits", "bs", "interpret")
+    jax.jit, static_argnames=("keep", "scale", "bits", "bs", "interpret")
 )
 def paged_attention_rung(
     q: jnp.ndarray,
@@ -118,158 +139,106 @@ def paged_attention_rung(
     v_planes: jnp.ndarray,
     mask: jnp.ndarray,
     keep: int,
+    scale: float,
     bits: int = 16,
     bs: int = 128,
     interpret: bool | None = None,
 ):
     """One precision rung over a page range.
 
-    q (B, Hkv, rep, hd) bf16; k/v_planes (bits, B, S, Hkv, hd//8) uint8;
-    mask (B, S) int8 (1 = valid token).  Returns unnormalised partials
-    (o (B, Hkv, rep, hd) f32, m (B, Hkv, rep) f32, l (B, Hkv, rep) f32)."""
+    q (B, 2, Hq, W) bf16 spread over element lanes; k/v_planes
+    (bits, B, S/16, 1, W) int32; mask (B, S/bs, 1, bs) int32 (1 = valid
+    token); scale = 1/sqrt(head_dim).  Returns unnormalised partials
+    (o (B, 2, Hq, W) f32, m (B, Hq, 1) f32, l (B, Hq, 1) f32)."""
     if interpret is None:
         interpret = default_interpret()
-    b, hkv, rep, hd = q.shape
-    s_total = k_planes.shape[2]
-    bs = min(bs, s_total)
-    assert s_total % bs == 0
-    n_s = s_total // bs
-    grid = (b, hkv, n_s)
-    from jax.experimental.pallas import tpu as pltpu
-
+    b, _, hq, w = q.shape
+    n_pages = k_planes.shape[2]
+    ppt = bs // PAGE
+    assert bs % PAGE == 0 and n_pages % ppt == 0, (n_pages, bs)
+    n_s = n_pages // ppt
+    planes_spec = pl.BlockSpec((keep, 1, ppt, 1, w),
+                               lambda b_, j: (0, b_, j, 0, 0))
     return pl.pallas_call(
         functools.partial(
-            _kernel, keep=keep, bits=bits, scale=1.0 / np.sqrt(hd), n_s=n_s
+            _rung_kernel, keep=keep, bits=bits, scale=scale, n_s=n_s,
         ),
-        grid=grid,
+        grid=(b, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b_, h, j: (b_, h, 0, 0)),
-            # Top `keep` planes only — the partial-plane KV fetch.
-            pl.BlockSpec((keep, 1, bs, 1, hd // 8), lambda b_, h, j: (0, b_, j, h, 0)),
-            pl.BlockSpec((keep, 1, bs, 1, hd // 8), lambda b_, h, j: (0, b_, j, h, 0)),
-            pl.BlockSpec((1, bs), lambda b_, h, j: (b_, j)),
+            pl.BlockSpec((1, 2, hq, w), lambda b_, j: (b_, 0, 0, 0)),
+            planes_spec,  # top `keep` planes only: the partial-plane fetch
+            planes_spec,
+            pl.BlockSpec((1, 1, 1, bs), lambda b_, j: (b_, j, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, rep), lambda b_, h, j: (b_, h, 0)),
-            pl.BlockSpec((1, 1, rep), lambda b_, h, j: (b_, h, 0)),
+            pl.BlockSpec((1, 2, hq, w), lambda b_, j: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, hq, 1), lambda b_, j: (b_, 0, 0)),
+            pl.BlockSpec((1, hq, 1), lambda b_, j: (b_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rep, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
+            jax.ShapeDtypeStruct((b, 2, hq, w), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, hd), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(hq, w),
         interpret=interpret,
     )(q, k_planes, v_planes, mask)
 
 
-def _unpack_tile_keeps(p, tok_keep, bits: int):
-    """(bits, bs, hd8) uint8 planes -> (bs, hd) bf16, with per-TOKEN live
-    plane counts: token t contributes planes [0, tok_keep[t]) and planes
-    tok_keep[t].. are zeroed arithmetically (their buffer rows may hold a
-    previous tile's bytes — the DMA loop never refreshed them)."""
-    byte_w = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 1, 8), 3)
-    bm8 = (p.astype(jnp.uint32)[..., None] >> (7 - byte_w)) & 1
-    plane_i = jax.lax.broadcasted_iota(jnp.int32, (bits, 1, 1, 1), 0)
-    live = plane_i < tok_keep.astype(jnp.int32)[None, :, None, None]
-    bm8 = jnp.where(live, bm8, 0)
-    plane_w = plane_i.astype(jnp.uint32)
-    u = (bm8 << ((bits - 1) - plane_w)).sum(axis=0)  # (bs, hd8, 8)
-    u16 = u.reshape(u.shape[0], -1).astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(u16, jnp.bfloat16)
-
-
-def _fused_kernel(q_ref, keeps_ref, mask_ref, kp_hbm, vp_hbm, o_ref,
+def _fused_kernel(keeps_ref, q_ref, mask_ref, kp_hbm, vp_hbm, o_ref,
                   m_scr, l_scr, acc_scr, k_buf, v_buf, k_sem, v_sem, *,
-                  bits: int, scale: float, n_s: int, bs: int,
-                  page_tokens: int):
+                  bits: int, scale: float, n_s: int, n_pages: int):
     """Single-launch ladder decode: walks the tile's per-page plane map
-    (SMEM) and gathers each page's planes [0, keep) from the packed HBM
-    planes with predicated async copies — planes keep..15 are never moved.
-    One online softmax across the whole tile sequence; the finish block
-    normalises in-kernel (guarding fully-masked rows), so there are no
-    partials to merge and no per-rung launches."""
+    (scalar-prefetched) and gathers each page's planes [0, keep) from the
+    HBM planes with predicated async copies — planes keep..15 are never
+    moved.  One online softmax across the whole tile sequence; the finish
+    block normalises in-kernel (guarding fully-masked rows), so there are
+    no partials to merge and no per-rung launches."""
     TRACE_COUNTS["fused"] += 1
-    from jax.experimental.pallas import tpu as pltpu
-
-    b_, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    ppt = bs // page_tokens  # pages per tile
+    b_, j = pl.program_id(0), pl.program_id(1)
+    ppt, w = k_buf.shape[1], k_buf.shape[3]
+    keeps = [keeps_ref[b_ * n_pages + j * ppt + pp] for pp in range(ppt)]
     for pp in range(ppt):
-        keep = keeps_ref[0, pp]
-        row0 = pp * page_tokens
 
-        def plane_body(i, _, keep=keep, row0=row0):
-            @pl.when(i < keep)
+        def plane_body(i, carry, pp=pp):
+            @pl.when(i < keeps[pp])
             def _copy():
-                src = pl.ds(j * bs + row0, page_tokens)
-                dst = pl.ds(row0, page_tokens)
-                ck = pltpu.make_async_copy(
-                    kp_hbm.at[i, b_, src, h, :], k_buf.at[i, dst, :], k_sem
-                )
-                cv = pltpu.make_async_copy(
-                    vp_hbm.at[i, b_, src, h, :], v_buf.at[i, dst, :], v_sem
-                )
+                src = j * ppt + pp
+                ck = pltpu.make_async_copy(kp_hbm.at[i, b_, src],
+                                           k_buf.at[i, pp], k_sem)
+                cv = pltpu.make_async_copy(vp_hbm.at[i, b_, src],
+                                           v_buf.at[i, pp], v_sem)
                 ck.start()
                 cv.start()
                 ck.wait()
                 cv.wait()
-            return 0
+            return carry
 
         jax.lax.fori_loop(0, bits, plane_body, 0)
 
-    # per-token live plane count = its page's keep (SMEM scalars -> (bs,))
-    tok_keep = jnp.concatenate([
-        jnp.full((page_tokens,), keeps_ref[0, pp], jnp.int32)
-        for pp in range(ppt)
-    ])
-    q = q_ref[...].reshape(q_ref.shape[2], q_ref.shape[3])  # (rep, hd)
-    k = _unpack_tile_keeps(k_buf[...], tok_keep, bits)
-    v = _unpack_tile_keeps(v_buf[...], tok_keep, bits)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (rep, bs)
-    ok = mask_ref[...].reshape(1, -1) > 0
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = l_scr[:, 0] * corr + p.sum(axis=1)
-    acc = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
-    acc_scr[...] = acc
+    # each page row's live plane count, broadcast over its words
+    row = jax.lax.broadcasted_iota(jnp.int32, (ppt, w), 0)
+    live = jnp.zeros((ppt, w), jnp.int32)
+    for pp in range(ppt):
+        live = jnp.where(row == pp, keeps[pp], live)
+    k = _unpack_tile(lambda i: k_buf[i, :, 0, :], bits, ppt, w, bits, live)
+    v = _unpack_tile(lambda i: v_buf[i, :, 0, :], bits, ppt, w, bits, live)
+    _attend(q_ref, mask_ref, k, v, m_scr, l_scr, acc_scr, first=j == 0,
+            scale=scale)
 
     @pl.when(j == n_s - 1)
     def _finish():
-        m = m_scr[:, 0]
-        l = l_scr[:, 0]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
+        m = m_scr[:, 0:1]
+        inv = 1.0 / jnp.maximum(l_scr[:, 0:1], 1e-30)
         # a row whose every position is masked: m stayed -inf, l == 0 —
-        # the division above is 0/eps only because acc stayed 0, but any
-        # residual (exp(-inf - -inf) = nan) must not escape: gate on m.
-        out = jnp.where((m > NEG_INF / 2)[:, None], out, 0.0)
-        o_ref[...] = out.reshape(o_ref.shape)
+        # any residual (exp(-inf - -inf) = nan) must not escape: gate on m
+        ok = m > NEG_INF / 2
+        for c in range(2):
+            o_ref[0, c] = jnp.where(ok, acc_scr[c] * inv, 0.0)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bits", "bs", "page_tokens", "interpret")
+    jax.jit, static_argnames=("scale", "bits", "bs", "interpret")
 )
 def paged_attention_fused(
     q: jnp.ndarray,
@@ -277,56 +246,50 @@ def paged_attention_fused(
     v_planes: jnp.ndarray,
     page_keeps: jnp.ndarray,
     mask: jnp.ndarray,
+    scale: float,
     bits: int = 16,
     bs: int = 128,
-    page_tokens: int = 16,
     interpret: bool | None = None,
 ):
     """One launch over the whole mixed-precision cache.
 
-    q (B, Hkv, rep, hd) bf16; k/v_planes (bits, B, S, Hkv, hd//8) uint8;
-    page_keeps (B, S/page_tokens) int32 — planes [0, keep) of each page are
-    gathered, the rest never read; mask (B, S) int8 (1 = valid token).
-    Requires S % bs == 0 and bs % page_tokens == 0 (page-aligned tiles).
-    Returns the NORMALISED output (B, Hkv, rep, hd) f32 — fully-masked rows
+    q (B, 2, Hq, W) bf16 spread over element lanes; k/v_planes
+    (bits, B, S/16, 1, W) int32; page_keeps (B, S/16) int32 — planes
+    [0, keep) of each page are gathered, the rest never read; mask
+    (B, S/bs, 1, bs) int32 (1 = valid token); scale = 1/sqrt(head_dim).
+    Returns the NORMALISED output (B, 2, Hq, W) f32 — fully-masked rows
     are zero."""
     if interpret is None:
         interpret = default_interpret()
-    b, hkv, rep, hd = q.shape
-    s_total = k_planes.shape[2]
-    assert s_total % bs == 0 and bs % page_tokens == 0, (s_total, bs)
-    n_s = s_total // bs
-    ppt = bs // page_tokens
-    grid = (b, hkv, n_s)
-    from jax.experimental.pallas import tpu as pltpu
-
+    b, _, hq, w = q.shape
+    n_pages = k_planes.shape[2]
+    ppt = bs // PAGE
+    assert bs % PAGE == 0 and n_pages % ppt == 0, (n_pages, bs)
+    n_s = n_pages // ppt
     return pl.pallas_call(
         functools.partial(
-            _fused_kernel, bits=bits, scale=1.0 / np.sqrt(hd), n_s=n_s,
-            bs=bs, page_tokens=page_tokens,
+            _fused_kernel, bits=bits, scale=scale, n_s=n_s, n_pages=n_pages,
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b_, h, j: (b_, h, 0, 0)),
-            # this tile's per-page plane counts, as SMEM scalars
-            pl.BlockSpec((1, ppt), lambda b_, h, j: (b_, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bs), lambda b_, h, j: (b_, j)),
-            # packed planes stay in HBM; the kernel gathers [0, keep) of
-            # each page itself — the predicated partial-plane fetch
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd), lambda b_, h, j: (b_, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, hd), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((bits, bs, hd // 8), jnp.uint8),
-            pltpu.VMEM((bits, bs, hd // 8), jnp.uint8),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_s),
+            in_specs=[
+                pl.BlockSpec((1, 2, hq, w), lambda b_, j, kp: (b_, 0, 0, 0)),
+                pl.BlockSpec((1, 1, 1, bs), lambda b_, j, kp: (b_, j, 0, 0)),
+                # planes stay in HBM; the kernel gathers [0, keep) of each
+                # page itself — the predicated partial-plane fetch
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, 2, hq, w),
+                                   lambda b_, j, kp: (b_, 0, 0, 0)),
+            scratch_shapes=_softmax_scratch(hq, w) + [
+                pltpu.VMEM((bits, ppt, 1, w), jnp.int32),
+                pltpu.VMEM((bits, ppt, 1, w), jnp.int32),
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, 2, hq, w), jnp.float32),
         interpret=interpret,
-    )(q, page_keeps, mask, k_planes, v_planes)
+    )(page_keeps.reshape(-1), q, mask, k_planes, v_planes)

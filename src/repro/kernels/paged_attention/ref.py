@@ -1,7 +1,21 @@
-"""Pure-jnp oracle for bit-plane paged decode attention.
+"""Pure-jnp oracle and packing for the bit-plane paged KV layout.
 
-KV-plane layout: planes (bits, B, S, Hkv, hd//8) uint8 — bit i (0 = MSB) of
-K[b, s, h, d] at planes[i, b, s, h, d//8] bit (7 - d%8).
+Device layout (one array per K and per V):
+
+    planes (bits, *lead, S/PAGE, 1, W) int32,   W = 128 * ceil(Hkv*hd / 256)
+
+A (plane, slot, page) unit is one row of ``W`` int32 words — the unit the
+decode kernel DMAs when the page's plane count says that plane is live.
+Elements ``n = h*hd + d`` of a token's KV vector are split into two halves:
+element ``n < W`` sits in the low 16 bits of word ``n``, element ``n >= W``
+in the high 16 bits of word ``n - W``; elements ``>= Hkv*hd`` are zero
+padding.  Within a half, bit ``t`` holds token ``t`` of the page, and plane
+``i`` (0 = MSB) holds bit ``15 - i`` of the bf16 value.  So the bits of a
+page's plane are packed along tokens: the kernel broadcasts a unit row over
+the page's 16 token rows and shifts by the token index, and every element
+stays in its own lane.  The unit is a multiple of 128 lanes because the TPU
+moves and tiles HBM in (1, 128)-word rows; the shape therefore does not
+record ``(Hkv, hd)``, and unpacking takes them as ``kv_shape``.
 """
 
 from __future__ import annotations
@@ -11,43 +25,69 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG_INF = -1e30
+#: tokens per device page: one 16-bit half-word holds a page's bits
+PAGE = 16
+
+
+def plane_words(n_elems: int) -> int:
+    """Words per (plane, page) unit for ``n_elems`` = Hkv*hd KV elements."""
+    return 128 * -(-n_elems // 256)
 
 
 def pack_kv_ref(kv: jnp.ndarray, bits: int = 16) -> jnp.ndarray:
-    """(B, S, Hkv, hd) bf16 -> (bits, B, S, Hkv, hd//8) uint8."""
+    """(*lead, S, Hkv, hd) bf16 -> (bits, *lead, S/PAGE, 1, W) int32."""
+    *lead, s, hkv, hd = kv.shape
+    if s % PAGE:
+        raise ValueError(f"bit-plane packing needs S % {PAGE} == 0, got {s}")
+    n = hkv * hd
+    w = plane_words(n)
     u = jax.lax.bitcast_convert_type(kv.astype(jnp.bfloat16), jnp.uint16)
-    u = u.astype(jnp.uint32)
-    shifts = jnp.arange(bits - 1, -1, -1, dtype=jnp.uint32)
-    bm = (u[None] >> shifts[:, None, None, None, None]) & 1
-    g = bm.reshape(bm.shape[:-1] + (bm.shape[-1] // 8, 8))
-    byte_w = jnp.array([1 << (7 - i) for i in range(8)], jnp.uint32)
-    return (g * byte_w).sum(-1).astype(jnp.uint8)
+    u = u.reshape(*lead, s // PAGE, PAGE, n).astype(jnp.uint32)
+    u = jnp.pad(u, [(0, 0)] * (len(lead) + 2) + [(0, 2 * w - n)])
+    tok = jnp.arange(PAGE, dtype=jnp.uint32)[:, None]
+    out = []
+    for i in range(bits):  # one plane at a time bounds the temporaries
+        bit = (u >> (bits - 1 - i)) & 1
+        half = (bit << tok).sum(axis=-2, dtype=jnp.uint32)  # (*lead, P, 2W)
+        out.append(half[..., :w] | (half[..., w:] << PAGE))
+    words = jnp.stack(out)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)[..., None, :]
 
 
-def unpack_kv_ref(planes: jnp.ndarray, keep: int, bits: int = 16) -> jnp.ndarray:
-    """planes -> (B, S, Hkv, hd) bf16, low planes zeroed (truncation)."""
-    shifts8 = jnp.arange(7, -1, -1, dtype=jnp.uint32)
-    bm = (planes[:keep].astype(jnp.uint32)[..., None] >> shifts8) & 1
-    bm = bm.reshape(bm.shape[:4] + (-1,))
-    plane_w = jnp.array([1 << (bits - 1 - i) for i in range(keep)], jnp.uint32)
-    u = (bm * plane_w[:, None, None, None, None]).sum(0).astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(u, jnp.bfloat16)
+def unpack_kv_ref(planes: jnp.ndarray, keep: int, kv_shape,
+                  bits: int = 16) -> jnp.ndarray:
+    """planes -> (*lead, S, Hkv, hd) bf16 with planes keep..bits-1 zeroed
+    (truncation); ``kv_shape`` = (Hkv, hd)."""
+    hkv, hd = kv_shape
+    words = jax.lax.bitcast_convert_type(planes[:keep, ..., 0, :], jnp.uint32)
+    half = jnp.concatenate([words & 0xFFFF, words >> PAGE], axis=-1)
+    tok = jnp.arange(PAGE, dtype=jnp.uint32)[:, None]
+    u = jnp.zeros(half.shape[1:-1] + (PAGE, half.shape[-1]), jnp.uint32)
+    for i in range(keep):
+        bit = (half[i, ..., None, :] >> tok) & 1  # (*lead, P, PAGE, 2W)
+        u = u | (bit << (bits - 1 - i))
+    *lead, p, _, _ = u.shape
+    u = u[..., :hkv * hd].reshape(*lead, p * PAGE, hkv, hd)
+    return jax.lax.bitcast_convert_type(u.astype(jnp.uint16), jnp.bfloat16)
 
 
-def ladder_attention_ref(q, k_planes, v_planes, ladder, valid_len, bits=16):
+def ladder_attention_ref(q, k_planes, v_planes, ladder, valid_len, kv_heads,
+                         bits=16):
     """q: (B, 1, Hp, hd); ladder: ((start_s, end_s, keep), ...) covering
-    [0, S).  Page ranges decode at their rung's precision; softmax runs over
-    the union.  Returns (B, 1, Hp, hd)."""
+    [0, S) at page boundaries.  Page ranges decode at their rung's
+    precision; softmax runs over the union.  Returns (B, 1, Hp, hd)."""
     b, _, hp, hd = q.shape
-    s_total = k_planes.shape[2]
-    hkv = k_planes.shape[3]
-    rep = hp // hkv
+    rep = hp // kv_heads
     k_parts, v_parts = [], []
     for (s0, s1, keep) in ladder:
-        k_parts.append(unpack_kv_ref(k_planes[:, :, s0:s1], keep, bits))
-        v_parts.append(unpack_kv_ref(v_planes[:, :, s0:s1], keep, bits))
+        pages = slice(s0 // PAGE, s1 // PAGE)
+        k_parts.append(unpack_kv_ref(k_planes[:, :, pages], keep,
+                                     (kv_heads, hd), bits))
+        v_parts.append(unpack_kv_ref(v_planes[:, :, pages], keep,
+                                     (kv_heads, hd), bits))
     k = jnp.concatenate(k_parts, axis=1)
     v = jnp.concatenate(v_parts, axis=1)
+    s_total = k.shape[1]
     head_map = np.arange(hp) // rep
     kh = k[:, :, head_map].astype(jnp.float32)
     vh = v[:, :, head_map].astype(jnp.float32)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 
 def _kernel(xdt_ref, da_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, state_scr,
             *, nc: int):
@@ -69,7 +71,7 @@ def _kernel(xdt_ref, da_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, state_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(xdt, da, b_h, c_h, h0, chunk: int = 256, interpret: bool = True):
+def ssd(xdt, da, b_h, c_h, h0, chunk: int = 256, interpret: bool | None = None):
     """xdt (B, L, H, P); da (B, L, H); b_h/c_h (B, L, H, N); h0 (B, H, N, P).
 
     Returns (y (B, L, H, P) f32, h_final (B, H, N, P) f32)."""
@@ -100,5 +102,5 @@ def ssd(xdt, da, b_h, c_h, h0, chunk: int = 256, interpret: bool = True):
             jax.ShapeDtypeStruct((bsz, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(xdt, da, b_h, c_h, h0)

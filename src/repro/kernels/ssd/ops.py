@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from repro.kernels.ssd import kernel as K
 
 
-def ssd(xdt, da, b_h, c_h, h0=None, chunk: int = 256, interpret: bool = True):
+def ssd(xdt, da, b_h, c_h, h0=None, chunk: int = 256, interpret: bool | None = None):
     """Drop-in for models.ssm.ssd_scan (same contract)."""
     bsz, l, h, p = xdt.shape
     n = b_h.shape[-1]

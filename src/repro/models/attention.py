@@ -19,16 +19,16 @@ Design notes (DESIGN.md §3):
   (Sq == 1 against a long cache with ``kv_valid`` masking), sliding-window
   (Mixtral) and bidirectional (Whisper encoder / cross-attention).
 
-* **Bit-plane device caches (ISSUE 5).**  A serving cache may store KV as
-  packed uint8 bit-planes (``{'k_planes','v_planes'}``, layout
-  (bits, B, S, Hkv, hd//8)) instead of dense bf16.  Decode appends pack the
-  new token's KV (:func:`~repro.kernels.paged_attention.ops.pack_kv_planes`
-  — lossless for bf16) and attention runs the Pallas paged-attention rung
-  kernel per ladder plane count, reading only the planes the per-page
-  ``kv_planes`` map prescribes — the device path of the paper's
-  bandwidth-proportionality claim.  Prefill chunks attend densely at full
-  precision (unpack -> flash -> pack the chunk back), since the ladder only
-  governs decode fetches.
+* **Bit-plane device caches.**  A serving cache may store KV as packed
+  bit-planes (``{'k_planes','v_planes'}``, layout (bits, B, S/16, 1, W)
+  int32, see ``kernels/paged_attention/ref.py``) instead of dense bf16.
+  Decode appends set the new token's bits in its page
+  (:func:`~repro.kernels.paged_attention.ops.insert_kv_token` — lossless
+  for bf16) and attention runs the Pallas paged-attention kernel, reading
+  only the planes the per-page ``kv_planes`` map prescribes — the device
+  path of the paper's bandwidth-proportionality claim.  Prefill chunks
+  attend densely at full precision (unpack -> flash -> repack), since the
+  ladder only governs decode fetches.
 """
 
 from __future__ import annotations
@@ -41,9 +41,10 @@ import numpy as np
 
 from repro.kernels.paged_attention.ops import (
     batched_ladder_paged_attention,
+    insert_kv_token,
     pack_kv_planes,
 )
-from repro.kernels.paged_attention.ref import unpack_kv_ref
+from repro.kernels.paged_attention.ref import PAGE, unpack_kv_ref
 from repro.models.layers import apply_rope, he_init, rope_angles
 
 NEG_INF = -1e30
@@ -424,20 +425,22 @@ def _bitplane_cache_step(q, k, v, hm, cache, *, pos, cache_len, window,
     """One step against a bit-plane packed device cache.
 
     cache: (k_planes, v_planes[, kv_pos]) — per-layer slices, planes
-    (bits, B, S, Hkv, hd//8) uint8.  kv_planes: (B, S/16) int32 per-device-
+    (bits, B, S/16, 1, W) int32.  kv_planes: (B, S/16) int32 per-device-
     page plane counts (the serving backend pushes the ladder assignment
     here); keeps: static tuple of the distinct plane counts kv_planes may
-    hold.  Decode (S == 1) packs the token and runs the Pallas rung kernel;
+    hold.  Decode (S == 1) inserts the token and runs the Pallas kernel;
     a prefill chunk (S > 1) attends densely at full precision — unpack,
-    run the matching dense/ring append, pack the updated rows back."""
+    run the matching dense/ring append, repack (a page's plane unit packs
+    its 16 tokens together, so a chunk that starts mid-page rewrites the
+    page's other tokens with their own bits)."""
     ring = len(cache) == 3
     kp, vp = cache[0], cache[1]
     cpos = cache[2] if ring else None
     bits = kp.shape[0]
     c = k.shape[1]
     if c > 1:  # prefill chunk: full-precision dense attend, pack on adoption
-        kd = unpack_kv_ref(kp, bits, bits)
-        vd = unpack_kv_ref(vp, bits, bits)
+        kd = unpack_kv_ref(kp, bits, k.shape[2:], bits)
+        vd = unpack_kv_ref(vp, bits, k.shape[2:], bits)
         if ring:
             out, ckd, cvd, cpos = _ring_chunk_append(
                 q, k, v, hm, kd, vd, cpos, pos=pos, cache_len=cache_len,
@@ -455,29 +458,23 @@ def _bitplane_cache_step(q, k, v, hm, cache, *, pos, cache_len, window,
             q, ckd, cvd, hm, q_pos=pos, kv_valid=cache_len + c,
             window=window, bidirectional=bidirectional,
         )
-        kp = jax.lax.dynamic_update_slice(kp, pack_kv_planes(k, bits),
-                                          (0, 0, cache_len, 0, 0))
-        vp = jax.lax.dynamic_update_slice(vp, pack_kv_planes(v, bits),
-                                          (0, 0, cache_len, 0, 0))
-        return out, (kp, vp)
-    # decode: pack-append the token at each row's own position, then the
-    # partial-plane rung kernel (per-slot valid lengths and ladders)
+        return out, (pack_kv_planes(ckd, bits), pack_kv_planes(cvd, bits))
+    # decode: insert the token at each row's own position, then the
+    # partial-plane kernel (per-slot valid lengths and ladders)
     ln = jnp.asarray(cache_len, jnp.int32)
     if ln.ndim == 0:
         ln = jnp.broadcast_to(ln, (kp.shape[1],))
     rows = jnp.arange(kp.shape[1])
-    s_cache = kp.shape[2]
+    s_cache = kp.shape[2] * PAGE
     slot = (ln % s_cache) if ring else jnp.clip(ln, 0, s_cache - 1)
-    pk = pack_kv_planes(k, bits)[:, :, 0]  # (bits, B, Hkv, hd8)
-    pv = pack_kv_planes(v, bits)[:, :, 0]
-    kp = kp.at[:, rows, slot].set(pk)
-    vp = vp.at[:, rows, slot].set(pv)
+    kp = insert_kv_token(kp, k[:, 0], slot)
+    vp = insert_kv_token(vp, v[:, 0], slot)
     if ring:
         cpos = cpos.at[rows, slot].set(ln.astype(cpos.dtype))
     out = batched_ladder_paged_attention(
         q, kp, vp, kv_planes, ln + 1,
         keeps=tuple(keeps) if keeps is not None else (bits,),
-        bits=bits, q_pos=pos, kv_pos=cpos,
+        kv_heads=k.shape[2], bits=bits, q_pos=pos, kv_pos=cpos,
         window=0 if bidirectional else window,
         kernel=decode_kernel,
     )
@@ -521,7 +518,7 @@ def attn_apply(
     positions < ``append_valid`` (dense caches don't need this — pad rows
     land past the true length and the next chunk/decode overwrites them).
     kv_planes/keeps: per-device-page ladder plane map + its static value
-    set, for bit-plane packed caches (uint8 plane tuples — see
+    set, for bit-plane packed caches (int32 plane tuples — see
     :func:`_bitplane_cache_step`); ignored for dense caches.
     decode_kernel: "fused" | "rung" — Pallas strategy for bit-plane decode
     (one plane-gathering launch vs one launch per ladder rung).
@@ -553,7 +550,7 @@ def attn_apply(
             window=window, bidirectional=bidirectional,
         )
         new_cache = (k, v)
-    elif cache[0].dtype == jnp.uint8:
+    elif cache[0].dtype == jnp.int32:
         # bit-plane packed device cache (serving device_kv='bitplane')
         out, new_cache = _bitplane_cache_step(
             q, k, v, hm, cache, pos=pos, cache_len=cache_len,

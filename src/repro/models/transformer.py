@@ -144,7 +144,7 @@ def run_stack(params, cfg, x, pos, cache=None, want_cache=False, remat=None,
     ``decode_kernel`` picks the bit-plane decode strategy ("fused"|"rung").
 
     Bit-plane serving caches carry {'k_planes','v_planes'} stacked
-    (L, bits, B, Smax, Hkv, hd//8) uint8 in place of {'k','v'}, plus a
+    (L, bits, B, Smax/16, 1, W) int32 in place of {'k','v'}, plus a
     'planes' map (B, Smax/16) int32 that is shared across layers (the
     serving ladder ranks on the last layer and applies everywhere, so it is
     closed over, not scanned); ``keeps`` is that map's static value set.
@@ -448,31 +448,29 @@ def flush_staging(cache, cfg):
             "sk": jnp.zeros_like(cache["sk"]), "sv": jnp.zeros_like(cache["sv"])}
 
 
-def bitplane_cache_from_dense(cache, page_tokens: int = 16, bits: int = 16):
+def bitplane_cache_from_dense(cache, bits: int = 16):
     """Convert a dense serving cache {'k','v'[,'pos'],...} into the
-    bit-plane device layout (ISSUE 5): {'k_planes','v_planes'} stacked
-    (L, bits, B, S, Hkv, hd//8) uint8 plus a per-device-page 'planes' map
-    (B, S/page_tokens) int32, initialised to full precision (the serving
-    ladder re-ranks it per slot).  Packing is a bf16 bitcast — an all-zero
-    dense cache packs to all-zero planes, and a populated one round-trips
-    bit-exactly at keep == bits."""
+    bit-plane device layout: {'k_planes','v_planes'} stacked
+    (L, bits, B, S/16, 1, W) int32 (``kernels/paged_attention/ref.py``)
+    plus a per-device-page 'planes' map (B, S/16) int32, initialised to
+    full precision (the serving ladder re-ranks it per slot).  Packing is
+    a bf16 bitcast — an all-zero dense cache packs to all-zero planes, and
+    a populated one round-trips bit-exactly at keep == bits.  S must be a
+    whole number of 16-token pages (ValueError otherwise)."""
     from repro.kernels.paged_attention.ops import pack_kv_planes
+    from repro.kernels.paged_attention.ref import PAGE
 
-    l, b, s, hkv, hd = cache["k"].shape
-    if hd % 8 != 0:
+    b, s = cache["k"].shape[1:3]
+    if s % PAGE:
         raise ValueError(
-            f"bit-plane packing needs head_dim % 8 == 0, got {hd}"
+            f"bit-plane caches need a whole number of {PAGE}-token pages, "
+            f"got S={s}"
         )
     out = {k: v for k, v in cache.items() if k not in ("k", "v")}
-
-    def pack(kv):  # (L, B, S, Hkv, hd) -> (L, bits, B, S, Hkv, hd//8)
-        p = pack_kv_planes(kv.reshape(l * b, s, hkv, hd), bits)
-        return jnp.moveaxis(p.reshape(bits, l, b, s, hkv, hd // 8), 0, 1)
-
-    out["k_planes"] = pack(cache["k"])
-    out["v_planes"] = pack(cache["v"])
-    n_pages = -(-s // page_tokens)
-    out["planes"] = jnp.full((b, n_pages), bits, jnp.int32)
+    # (L, B, S, Hkv, hd) -> (bits, L, B, S/16, 1, W) -> (L, bits, ...)
+    out["k_planes"] = jnp.moveaxis(pack_kv_planes(cache["k"], bits), 0, 1)
+    out["v_planes"] = jnp.moveaxis(pack_kv_planes(cache["v"], bits), 0, 1)
+    out["planes"] = jnp.full((b, s // PAGE), bits, jnp.int32)
     return out
 
 

@@ -38,17 +38,12 @@ def dp_axes(mesh: Mesh) -> tuple:
 
 
 def abstract_mesh(sizes, names):
-    """Device-free mesh for planning/routing decisions (jax-version
-    compatible): >=0.5 takes (sizes, names); 0.4.x takes one
-    ((name, size), ...) shape tuple.  The serving ShardedBackend uses this
-    to consult :func:`cache_pspecs` for KV-head vs sequence routing without
-    touching device state."""
+    """Device-free mesh for planning/routing decisions.  The serving
+    ShardedBackend uses this to consult :func:`cache_pspecs` for KV-head
+    vs sequence routing without touching device state."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def axes_size(mesh: Mesh, axes) -> int:

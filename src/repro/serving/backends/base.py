@@ -308,10 +308,10 @@ class KVBackend(abc.ABC):
                 f"device_kv must be 'dense' or 'bitplane', got "
                 f"{cfg.device_kv!r}"
             )
-        if cfg.device_kv == "bitplane" and mcfg.head_dim % 8 != 0:
+        if cfg.device_kv == "bitplane" and cfg.max_ctx % PAGE_TOKENS:
             raise ValueError(
-                f"bit-plane packing needs head_dim % 8 == 0, got "
-                f"{mcfg.head_dim}"
+                f"bit-plane device caches need max_ctx to be a whole "
+                f"number of {PAGE_TOKENS}-token pages, got {cfg.max_ctx}"
             )
 
     # ----------------------------------------------------------------- tiers
@@ -389,13 +389,13 @@ class KVBackend(abc.ABC):
 
     def _apply_device_layout(self, cache):
         """Convert the model's dense cache to the configured device layout
-        (``device_kv='bitplane'``: packed uint8 planes + a per-page plane
+        (``device_kv='bitplane'``: packed int32 planes + a per-page plane
         map the ladder assignment is pushed into)."""
         if self.device_kv != "bitplane":
             return cache
         from repro.models.transformer import bitplane_cache_from_dense
 
-        return bitplane_cache_from_dense(cache, page_tokens=PAGE_TOKENS)
+        return bitplane_cache_from_dense(cache)
 
     def device_keeps(self) -> Optional[tuple]:
         """Static plane-count set the device decode kernel may be asked to
@@ -433,15 +433,9 @@ class KVBackend(abc.ABC):
         into this slot's rows [0, s) (bit-plane layouts pack on adoption)."""
         cache = self.ensure_cache()
         if self.device_kv == "bitplane":
-            from repro.kernels.paged_attention.ops import pack_kv_planes
-
-            # (L, 1, s, Hkv, hd) -> (bits, L, s, Hkv, hd8) -> (L, bits, ...)
             for name in ("k", "v"):
-                packed = jnp.moveaxis(
-                    pack_kv_planes(pcache[name][:, 0, :s]), 0, 1
-                )
-                dst = name + "_planes"
-                cache[dst] = cache[dst].at[:, :, slot_id, :s].set(packed)
+                self._write_device_rows(name + "_planes", slot_id,
+                                        slice(0, s), pcache[name][:, 0, :s])
             return
         cache["k"] = cache["k"].at[:, slot_id, :s].set(pcache["k"][:, 0])
         cache["v"] = cache["v"].at[:, slot_id, :s].set(pcache["v"][:, 0])
@@ -454,6 +448,21 @@ class KVBackend(abc.ABC):
     def _device_rows(self, t0: int, t1: int):
         """Cache sequence-axis index holding absolute tokens [t0, t1)."""
         return slice(t0, t1)
+
+    def _kv_shape(self) -> Tuple[int, int]:
+        return self.mcfg.n_kv_heads, self.mcfg.head_dim
+
+    def _write_device_rows(self, name: str, slot_id: int, rows,
+                           dense) -> None:
+        """Write dense KV (L, T, Hkv, hd) into this slot's device ``rows``
+        of the bit-plane cache ``name`` (L, bits, B, S/16, 1, W)."""
+        from repro.kernels.paged_attention.ops import write_kv_rows
+
+        col = jnp.moveaxis(self._cache[name][:, :, slot_id], 1, 0)
+        col = write_kv_rows(col, rows, dense)
+        self._cache[name] = self._cache[name].at[:, :, slot_id].set(
+            jnp.moveaxis(col, 0, 1)
+        )
 
     def stored_layers(self) -> int:
         n_layers = self.mcfg.n_layers
@@ -475,16 +484,13 @@ class KVBackend(abc.ABC):
         rows = self._device_rows(t0, t1)
         t = t1 - t0
         if self.device_kv == "bitplane":
-            from repro.kernels.paged_attention.ref import unpack_kv_ref
+            from repro.kernels.paged_attention.ops import read_kv_rows
 
             out = []
             for name in ("k_planes", "v_planes"):
-                # (ls, bits, t, Hkv, hd8) -> unpack layers as the batch axis
-                pl = jnp.moveaxis(
-                    self._cache[name][:ls, :, slot_id, rows], 1, 0
-                )
-                bits = pl.shape[0]
-                dense = unpack_kv_ref(pl, bits, bits)  # (ls, t, Hkv, hd)
+                # (ls, bits, S/16, 1, W) -> unpack layers as the batch axis
+                pl = jnp.moveaxis(self._cache[name][:ls, :, slot_id], 1, 0)
+                dense = read_kv_rows(pl, rows, self._kv_shape())
                 out.append(np.asarray(dense.reshape(ls, t, -1)))
             return tuple(out)
         k = np.asarray(self._cache["k"][:ls, slot_id, rows], np.float32)
@@ -646,14 +652,11 @@ class KVBackend(abc.ABC):
         n_layers = entry.k.shape[0]
         rows = self._device_rows(lo, m)
         if self.device_kv == "bitplane":
-            from repro.kernels.paged_attention.ops import pack_kv_planes
-
             for name, arr in (("k_planes", entry.k), ("v_planes", entry.v)):
                 dense = jnp.asarray(arr[:, o:o + t]).reshape(
                     n_layers, t, hkv, hd
                 )
-                packed = jnp.moveaxis(pack_kv_planes(dense), 0, 1)
-                cache[name] = cache[name].at[:, :, slot_id, rows].set(packed)
+                self._write_device_rows(name, slot_id, rows, dense)
             return
         for name, arr in (("k", entry.k), ("v", entry.v)):
             dense = jnp.asarray(arr[:, o:o + t]).reshape(
@@ -885,11 +888,10 @@ class KVBackend(abc.ABC):
                     k = k.at[tok - t0].set(
                         self._cache["sk"][-1, slot_id, tok - sb])
             return k
-        from repro.kernels.paged_attention.ref import unpack_kv_ref
+        from repro.kernels.paged_attention.ops import read_kv_rows
 
-        pl = self._cache["k_planes"][-1][:, slot_id][:, rows]
-        bits = pl.shape[0]
-        return unpack_kv_ref(pl[:, None], bits, bits)[0]
+        pl = self._cache["k_planes"][-1][:, slot_id]  # (bits, S/16, 1, W)
+        return read_kv_rows(pl, rows, self._kv_shape())
 
     def _device_page(self, page_idx: int) -> int:
         """Device plane-map column holding this absolute page (ring layouts

@@ -161,7 +161,7 @@ class EngineConfig:
     shards: int = 2
     #: device KV-cache layout (ISSUE 5): 'dense' — bf16 rows, decode reads
     #: full precision regardless of the ladder (bandwidth savings are
-    #: accounting-only); 'bitplane' — packed uint8 bit-planes, decode runs
+    #: accounting-only); 'bitplane' — packed int32 bit-planes, decode runs
     #: the Pallas partial-plane rung kernel and reads exactly the planes
     #: the ladder prescribes (``report()["device_bytes_read"]`` equals the
     #: controller's plane-scaled kv_read).  The default honours the

@@ -165,8 +165,8 @@ class TestPagedAttention:
         k = _bf16(rng, B, S, Hkv, hd)
         v = _bf16(rng, B, S, Hkv, hd)
         kp, vp = pack_kv_planes(k), pack_kv_planes(v)
-        got = ladder_paged_attention(q, kp, vp, ladder, valid)
-        want = ladder_attention_ref(q, kp, vp, ladder, valid)
+        got = ladder_paged_attention(q, kp, vp, ladder, valid, Hkv)
+        want = ladder_attention_ref(q, kp, vp, ladder, valid, Hkv)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), atol=0.06
         )
@@ -193,7 +193,8 @@ class TestPagedAttention:
         pp[2] = [4, 16, 4, 8, 16, 8]  # scattered — no contiguous-rung luxury
         valid = np.array([96, 77, 50], np.int32)
         got = batched_ladder_paged_attention(
-            q, kp, vp, jnp.asarray(pp), jnp.asarray(valid), keeps=(4, 8, 16)
+            q, kp, vp, jnp.asarray(pp), jnp.asarray(valid), keeps=(4, 8, 16),
+            kv_heads=Hkv,
         )
         for b in range(B):
             runs = []
@@ -204,7 +205,7 @@ class TestPagedAttention:
                     runs.append((p * 16, (p + 1) * 16, int(pp[b, p])))
             want = ladder_attention_ref(
                 q[b:b + 1], kp[:, b:b + 1], vp[:, b:b + 1], runs,
-                int(valid[b]),
+                int(valid[b]), Hkv,
             )
             np.testing.assert_allclose(
                 np.asarray(got[b:b + 1], np.float32),
@@ -212,7 +213,8 @@ class TestPagedAttention:
             )
         # a slot with nothing valid returns zeros, not softmax garbage
         idle = batched_ladder_paged_attention(
-            q, kp, vp, jnp.asarray(pp), jnp.zeros(B, jnp.int32), keeps=(16,)
+            q, kp, vp, jnp.asarray(pp), jnp.zeros(B, jnp.int32), keeps=(16,),
+            kv_heads=Hkv,
         )
         assert np.all(np.asarray(idle, np.float32) == 0)
 
@@ -234,15 +236,18 @@ class TestPagedAttention:
         valid = jnp.asarray([96, 50, 17], jnp.int32)
         args = (q, kp, vp, jnp.asarray(pp), valid)
         fused = batched_ladder_paged_attention(*args, keeps=(4, 8, 16),
+                                               kv_heads=Hkv,
                                                kernel="fused")
         rung = batched_ladder_paged_attention(*args, keeps=(4, 8, 16),
+                                              kv_heads=Hkv,
                                               kernel="rung")
         np.testing.assert_allclose(
             np.asarray(fused, np.float32), np.asarray(rung, np.float32),
             atol=0.01,
         )
         with pytest.raises(ValueError, match="kernel"):
-            batched_ladder_paged_attention(*args, keeps=(16,), kernel="warp")
+            batched_ladder_paged_attention(*args, keeps=(16,), kv_heads=Hkv,
+                                           kernel="warp")
 
     @pytest.mark.parametrize("kernel", ["fused", "rung"])
     def test_fully_masked_row_returns_zeros(self, kernel, rng):
@@ -265,7 +270,7 @@ class TestPagedAttention:
         valid = jnp.asarray([64, 64, 0], jnp.int32)  # row 2: nothing valid
         out = np.asarray(batched_ladder_paged_attention(
             q, kp, vp, jnp.asarray(pp), valid, keeps=(4, 8, 16),
-            kernel=kernel,
+            kv_heads=Hkv, kernel=kernel,
         ), np.float32)
         assert np.all(np.isfinite(out))
         assert np.all(out[1] == 0) and np.all(out[2] == 0)
@@ -275,7 +280,7 @@ class TestPagedAttention:
         """ISSUE 5 satellite: interpret=None resolves from the JAX backend
         (interpreter on CPU, compiled elsewhere) with an env override — the
         old hardcoded True silently interpreted on TPU."""
-        from repro.kernels.paged_attention.kernel import default_interpret
+        from repro.kernels import default_interpret
 
         monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
         assert default_interpret() == (jax.default_backend() == "cpu")
@@ -283,6 +288,21 @@ class TestPagedAttention:
         assert default_interpret() is False
         monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
         assert default_interpret() is True
+
+    def test_interpret_override_refused_on_accelerator(self, monkeypatch):
+        """The interpreter override is for CPU debugging: on an accelerator
+        it raises instead of silently interpreting every kernel, while the
+        default and the compile override resolve to compiled Mosaic."""
+        from repro.kernels import default_interpret
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+        assert default_interpret() is False
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+        assert default_interpret() is False
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+        with pytest.raises(ValueError, match="CPU debugging only"):
+            default_interpret()
 
 
 # ------------------------------------------------------------------- ssd

@@ -674,11 +674,19 @@ def test_staged_decode_unsupported_combinations_raise():
 
 
 def test_bitplane_rejects_unpackable_head_dim(smoke_model):
-    cfg_bad = dataclasses.replace(get_config("smollm-135m", smoke=True),
+    """The int32 plane layout packs KV elements, not bytes, so every
+    head_dim packs (head_dim 12 included).  What it cannot pack is a
+    partial page: a max_ctx that is not a whole number of 16-token pages
+    is refused up front, as is an unknown device_kv."""
+    cfg_odd = dataclasses.replace(get_config("smollm-135m", smoke=True),
                                   head_dim=12, n_heads=4, n_kv_heads=2)
-    model = build_model(cfg_bad)
-    with pytest.raises(ValueError, match="head_dim"):
-        make_backend(model, _cfg("paged", 1, device_kv="bitplane"))
+    backend = make_backend(build_model(cfg_odd),
+                           _cfg("paged", 1, device_kv="bitplane"))
+    assert backend.ensure_cache()["k_planes"].dtype == jnp.int32
+    with pytest.raises(ValueError, match="max_ctx"):
+        make_backend(smoke_model[0], EngineConfig(
+            max_batch=2, max_ctx=100, prefill_mode="padded",
+            device_kv="bitplane"))
     with pytest.raises(ValueError, match="device_kv"):
         make_backend(smoke_model[0], _cfg("paged", 1, device_kv="fp4"))
 

@@ -4,7 +4,7 @@ production mesh topology, without touching device state (AbstractMesh)."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import AbstractMesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, arch_shapes, get_config
 from repro.models.model import build_model, input_specs
@@ -12,19 +12,10 @@ from repro.optim.adamw import adamw_init
 from repro.runtime import sharding
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: >=0.5 takes (sizes, names); 0.4.x
-    takes a single ((name, size), ...) shape tuple."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def _mesh(multi=False):
     if multi:
-        return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return _abstract_mesh((16, 16), ("data", "model"))
+        return sharding.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return sharding.abstract_mesh((16, 16), ("data", "model"))
 
 
 def _check_divisible(spec_tree, shape_tree, mesh):
