@@ -25,6 +25,16 @@ def pct(x: float) -> str:
     return f"{100 * x:.1f}%"
 
 
+def decode_tok_per_s(rep: dict) -> float:
+    """Decode tokens over the host seconds from each decode dispatch to its
+    tokens on the host: the ``decode_dispatch``, ``kv_flush`` and
+    ``decode_wait`` phases of ``report()["host_s_by_phase"]``.  Off a chip
+    this is a host number, not a device metric."""
+    ph = rep["host_s_by_phase"]
+    secs = ph["decode_dispatch"] + ph["kv_flush"] + ph["decode_wait"]
+    return rep["decode_tokens"] / secs if secs else 0.0
+
+
 def time_call(fn, *args, reps: int = 3, **kw):
     fn(*args, **kw)  # warm
     t0 = time.perf_counter()

@@ -42,7 +42,7 @@ import json
 
 import numpy as np
 
-from benchmarks.common import fmt_table, pct
+from benchmarks.common import decode_tok_per_s, fmt_table, pct
 
 
 def _mixed_requests(n, seed, vocab):
@@ -149,7 +149,7 @@ def run(n_requests: int = 16, rate: float = 0.6, seed: int = 0,
                 # on BOTH kernel strategies
                 assert rep["device_bytes_read"] == rep["kv_read_device_bytes"]
             dec = max(1, rep["decode_tokens"])
-            tok_s = rep.get("decode_tok_per_s", 0)
+            tok_s = decode_tok_per_s(rep)
             bpt = rep["device_bytes_read"] / dec
             variant = (device_kv if device_kv == "dense"
                        else f"{device_kv}/{kernel}")

@@ -33,7 +33,7 @@ import os
 
 import numpy as np
 
-from benchmarks.common import fmt_table
+from benchmarks.common import decode_tok_per_s, fmt_table
 
 
 def _wave_requests(n, share_factor, seed, prefix_pages=6):
@@ -106,7 +106,7 @@ def run(n_requests: int = 16, seed: int = 0, share_factors=(1, 4, 8),
         px = rep["prefix"]
         kv_writes = rep["engine"]["serviced_jobs"].get("KV_WRITE", 0)
         row = {
-            "decode_tok_per_s": rep.get("decode_tok_per_s", 0),
+            "decode_tok_per_s": decode_tok_per_s(rep),
             "ttft_p50_ns": lat["p50"], "ttft_p99_ns": lat["p99"],
             "hit_ratio": px.get("hit_ratio", 0.0),
             "requests_matched": px.get("requests_matched", 0),

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import fmt_table, pct
+from benchmarks.common import decode_tok_per_s, fmt_table, pct
 
 
 def _mixed_requests(n, seed, vocab):
@@ -96,7 +96,7 @@ def run(n_requests: int = 24, rate: float = 0.6, shards: int = 2,
                    arrivals, max_steps=max_steps)
         rows.append([
             name,
-            f"{rep.get('decode_tok_per_s', 0):.1f}",
+            f"{decode_tok_per_s(rep):.1f}",
             pct(rep.get("mean_batch_occupancy", 0)),
             pct(rep.get("kv_capacity_saving", 0)),
             pct(rep.get("kv_bandwidth_saving", 0)),
@@ -105,7 +105,7 @@ def run(n_requests: int = 24, rate: float = 0.6, shards: int = 2,
             f"{rep['engine_modeled_latency_ns'] / 1e3:.1f}us",
         ])
         out[name] = {
-            "decode_tok_per_s": rep.get("decode_tok_per_s", 0),
+            "decode_tok_per_s": decode_tok_per_s(rep),
             "kv_capacity_saving": rep.get("kv_capacity_saving", 0),
             "kv_bandwidth_saving": rep.get("kv_bandwidth_saving", 0),
             "engine_utilization": rep["engine_utilization"],

@@ -2,9 +2,10 @@
 
 Drives the continuous-batching scheduler with a Poisson arrival trace of
 mixed-length requests and reports decode tokens/s, batch occupancy, prefill
-compile count / wall time, and the KV capacity/bandwidth savings the
-compressed store + dynamic-quantization ladder deliver at steady state
-(normalised per 1k requests).  Three modes, each on a FRESH model object so
+compile count / host seconds (``host_s_by_phase["prefill_dispatch"]``), and
+the KV capacity/bandwidth savings the compressed store +
+dynamic-quantization ladder deliver at steady state (normalised per 1k
+requests).  Three modes, each on a FRESH model object so
 prefill numbers include its own compiles (that is the point of bucketing):
 
 * ``bucketed``   — chunked prefill over power-of-two length buckets
@@ -25,7 +26,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import fmt_table, pct
+from benchmarks.common import decode_tok_per_s, fmt_table, pct
 
 
 def _mixed_requests(n, seed, vocab, max_new_choices=(4, 8, 16, 24)):
@@ -127,8 +128,8 @@ def run(n_requests: int = 24, rate: float = 0.6, seed: int = 0,
         rows.append([
             name,
             f"{rep['prefill_compiles']:.0f}",
-            f"{rep['prefill_s']:.2f}s",
-            f"{rep.get('decode_tok_per_s', 0):.1f}",
+            f"{rep['host_s_by_phase']['prefill_dispatch']:.2f}s",
+            f"{decode_tok_per_s(rep):.1f}",
             f"{rep['decode_steps']:.0f}",
             pct(rep.get("mean_batch_occupancy", 0)),
             pct(rep.get("kv_capacity_saving", 0)),
@@ -137,9 +138,9 @@ def run(n_requests: int = 24, rate: float = 0.6, seed: int = 0,
         ])
         out[name] = {
             "prefill_compiles": rep["prefill_compiles"],
-            "prefill_s": rep["prefill_s"],
+            "prefill_s": rep["host_s_by_phase"]["prefill_dispatch"],
             "prefill_tokens": rep["prefill_tokens"],
-            "decode_tok_per_s": rep.get("decode_tok_per_s", 0),
+            "decode_tok_per_s": decode_tok_per_s(rep),
             "decode_steps": rep["decode_steps"],
             "occupancy": rep.get("mean_batch_occupancy", 0),
             "kv_capacity_saving": rep.get("kv_capacity_saving", 0),
@@ -153,7 +154,8 @@ def run(n_requests: int = 24, rate: float = 0.6, seed: int = 0,
     print(f"\n[serving] bucketed admission: "
           f"{bucketed['prefill_compiles']:.0f} prefill compiles vs "
           f"{leftpad['prefill_compiles']:.0f} left-pad "
-          f"({bucketed['prefill_s']:.2f}s vs {leftpad['prefill_s']:.2f}s "
+          f"({out['bucketed']['prefill_s']:.2f}s vs "
+          f"{out['left-pad']['prefill_s']:.2f}s "
           f"prefill); pad-free prefill tokens "
           f"{bucketed['prefill_tokens']:.0f} vs "
           f"{leftpad['prefill_tokens']:.0f}")

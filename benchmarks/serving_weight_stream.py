@@ -36,7 +36,7 @@ import os
 
 import numpy as np
 
-from benchmarks.common import fmt_table, pct
+from benchmarks.common import decode_tok_per_s, fmt_table, pct
 
 
 def _mixed_requests(n, seed, vocab):
@@ -101,7 +101,7 @@ def run(n_requests: int = 12, rate: float = 0.6, seed: int = 0,
                          max_steps=max_steps)
         tokens[mode] = outs
         dec = max(1, rep["decode_tokens"])
-        tok_s = rep.get("decode_tok_per_s", 0)
+        tok_s = decode_tok_per_s(rep)
         w = rep["weights"]
         if mode == "resident":
             bpt, saving, stall = 0.0, 0.0, 0.0

@@ -79,7 +79,7 @@ def main():
     rep = sched.report()
     print(f"\n[serve] {rep['requests_completed']:.0f} requests, "
           f"{rep['decode_tokens']:.0f} decode tokens in {dt:.1f}s "
-          f"({rep.get('decode_tok_per_s', 0):.1f} tok/s on CPU), "
+          f"({rep['decode_tokens'] / dt:.1f} tok/s on CPU), "
           f"mean occupancy {rep.get('mean_batch_occupancy', 0):.0%}")
     print(f"[serve] KV capacity saving (clustered+delta+codec store): "
           f"{rep.get('kv_capacity_saving', 0):.1%}")

@@ -73,6 +73,7 @@ from repro.serving.kv_cache import (
     page_chain_hashes,
     prefix_seq_id,
 )
+from repro.telemetry import steps
 from repro.telemetry.collector import NULL_COLLECTOR
 
 #: stat keys the backend mutates on the (shared) scheduler stats dict
@@ -477,7 +478,14 @@ class KVBackend(abc.ABC):
         bf16 bitcast, so the copy is bit-identical to the dense layout's.
         ``layers`` overrides the layer count (prefix-index snapshots copy
         ALL layers: adoption rebuilds the whole device column, not just the
-        compressed-store's capped subset)."""
+        compressed-store's capped subset).  The copied bytes go to the
+        step's ``d2h_bytes`` counter."""
+        k, v = self._copy_kv_host(slot_id, t0, t1, layers)
+        steps.count("d2h_bytes", k.nbytes + v.nbytes)
+        return k, v
+
+    def _copy_kv_host(self, slot_id: int, t0: int, t1: int,
+                      layers: Optional[int]):
         import ml_dtypes
 
         ls = self.stored_layers() if layers is None else layers
@@ -978,8 +986,13 @@ class KVBackend(abc.ABC):
             # pass's prefetch-depth layers (the double buffer)
             for ws in self.streamers:
                 ws.begin_pass()
+        jobs = queued = 0
         for tier in self.tiers:
-            tier.engine.tick()
+            done = tier.engine.tick()
+            jobs += done["serviced_jobs"]
+            queued += done["queue_depth"]
+        steps.count("memctl_jobs", jobs)
+        steps.count("memctl_queue", queued)
         if compute:
             # any current-pass layer the window could not service is a
             # stall, charged to modeled latency (engine_time_ns)

@@ -67,7 +67,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 import weakref
 from collections import deque
 from typing import Deque, Dict, List, Optional
@@ -83,6 +82,7 @@ from repro.models.model import Model
 from repro.serving.backends import make_backend
 from repro.serving.kv_cache import PAGE_TOKENS
 from repro.serving.sampler import SamplerConfig, sample, sample_slots
+from repro.telemetry import steps
 from repro.telemetry.collector import TelemetryConfig, make_collector
 
 
@@ -302,17 +302,29 @@ _JIT_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 def _jitted(model: Model, keeps: tuple | None = None,
             decode_kernel: str = "fused"):
+    """(prefill, decode, prefill_chunk) jits of named functions, so a
+    profiler trace shows ``jit_decode(...)`` and ``jit_prefill_chunk(...)``
+    (the name is part of the program, and of its compile-cache key)."""
     per = _JIT_CACHE.setdefault(model, {})
     key = (keeps, decode_kernel)
     try:
         return per[key]
     except KeyError:
-        chunk = (jax.jit(model.prefill_chunk)
+        def prefill(p, b):
+            return model.prefill(p, b)
+
+        def decode(p, t, c):
+            if keeps is None:
+                return model.decode(p, t, c)
+            return model.decode(p, t, c, keeps=keeps,
+                                decode_kernel=decode_kernel)
+
+        def prefill_chunk(p, t, c, slot, start, last):
+            return model.prefill_chunk(p, t, c, slot, start, last)
+
+        chunk = (jax.jit(prefill_chunk)
                  if model.prefill_chunk is not None else None)
-        decode = (jax.jit(model.decode) if keeps is None else
-                  jax.jit(lambda p, t, c: model.decode(
-                      p, t, c, keeps=keeps, decode_kernel=decode_kernel)))
-        fns = (jax.jit(model.prefill), decode, chunk)
+        fns = (jax.jit(prefill), jax.jit(decode), chunk)
         per[key] = fns
         return fns
 
@@ -368,8 +380,10 @@ class ContinuousScheduler:
             "kv_peak_stored_bytes": 0, "kv_peak_logical_bytes": 0,
             "admits_deferred": 0, "backpressure_steps": 0,
             "requests_shed": 0, "prefill_chunks_skipped": 0,
-            "prefill_s": 0.0, "decode_s": 0.0,
         }
+        #: host nanoseconds of this scheduler's steps, per phase
+        #: (``steps.PHASES``); ``report()["host_s_by_phase"]``
+        self._phase_ns: Dict[str, int] = dict.fromkeys(steps.PHASES, 0)
         # the memory tier: store(s) + controller(s) + lane engine(s) live
         # behind the protocol; the backend mutates the shared stats dict
         self.telemetry = make_collector(cfg.telemetry)
@@ -480,6 +494,10 @@ class ContinuousScheduler:
         step -> flush prefill storage -> commit decode -> engine tick ->
         retire.  Returns the requests retired this step.
 
+        Each phase is a ``steps.span`` (``serve.<phase>`` in a profiler
+        trace, nanoseconds in the step's record in ``steps.LOG``); the
+        phases tile the step, its idle branch included.
+
         True async admission (ISSUE 5 satellite): prefill chunks are
         DISPATCHED without a host sync — the old per-chunk
         ``block_until_ready`` serialized every chunk ahead of the decode
@@ -497,33 +515,50 @@ class ContinuousScheduler:
         — prefill/decode page writes, decode fetches, re-activations — is
         serviced against each tier's per-step lane budget; leftovers stay
         queued for later windows."""
-        self._admit_tick()
-        progressed = self._prefill_tick()
-        if self.decoding == 0:
-            self._flush_prefill_progress(progressed)
-            self.backend.tick()   # engine windows track wall steps
-            self._note_step()
-            self.step_count += 1  # idle tick: arrival traces keyed on
-            return []             # step_count must still advance time
-        pending_decode = self._decode_dispatch()
-        self._flush_prefill_progress(progressed)
-        self._decode_commit(pending_decode)
-        self.backend.tick()
-        if self.cfg.store_kv_compressed:
-            self.backend.note_peaks()
-        self._note_step()
-        self.step_count += 1
-        return self._retire_finished()
+        with steps.step(self.step_count) as record:
+            done = self._step(record)
+        for phase, ns in record["phase_ns"].items():
+            self._phase_ns[phase] += ns
+        return done
 
-    def _note_step(self) -> None:
-        """One structured telemetry record per scheduler step: occupancy,
-        waiting queue, engine backlog (the Perfetto counter tracks)."""
+    def _step(self, record: dict) -> List[Request]:
+        with steps.span("admit"):
+            self._admit_tick()
+        with steps.span("prefill_dispatch"):
+            progressed = self._prefill_tick()
+        if self.decoding == 0:
+            with steps.span("kv_flush"):
+                self._flush_prefill_progress(progressed)
+            with steps.span("engine_tick"):
+                self.backend.tick()  # engine windows track wall steps
+            with steps.span("retire"):
+                self._note_step(record)
+                self.step_count += 1  # idle tick: arrival traces keyed on
+            return []                 # step_count must still advance time
+        with steps.span("decode_dispatch"):
+            pending_decode = self._decode_dispatch()
+        with steps.span("kv_flush"):
+            self._flush_prefill_progress(progressed)
+        self._decode_commit(pending_decode)
+        with steps.span("engine_tick"):
+            self.backend.tick()
+        with steps.span("retire"):
+            if self.cfg.store_kv_compressed:
+                self.backend.note_peaks()
+            self._note_step(record)
+            self.step_count += 1
+            return self._retire_finished()
+
+    def _note_step(self, record: dict) -> None:
+        """Hand the step's record (``steps.step``) to the collector, with
+        occupancy, waiting queue and engine backlog (the Perfetto counter
+        tracks); the record's phases and end fill in when the step
+        closes."""
         if self.telemetry.enabled:
-            self.telemetry.on_step({
-                "active": self.active, "decoding": self.decoding,
-                "waiting": len(self._waiting),
-                "backlog": self.backend.backlog(),
-            })
+            record.update(active=self.active, decoding=self.decoding,
+                          waiting=len(self._waiting),
+                          backlog=self.backend.backlog())
+            self.telemetry.on_step(record)
 
     def _flush_prefill_progress(self, progressed) -> None:
         """Hand this step's completed prompt spans to the backend (page
@@ -580,16 +615,14 @@ class ContinuousScheduler:
         req.admit_step = self.step_count
         if self.telemetry.enabled:
             self.telemetry.on_admit(req.rid, slot_id)
-        if self._mode == "padded":
-            self._prefill_padded(slot_id)
 
     def _prefill_tick(self) -> List[tuple]:
-        """Advance every mid-prefill slot (bucketed mode; the padded path
-        completes inside ``_admit``).  Overlap policy — the double-buffered
-        slot join: while other slots decode, a joining prompt advances only
-        ``prefill_chunks_per_step`` chunks per step so admission never
-        stalls the batch; with nothing decoding, the prompt runs to
-        completion now (nobody is waiting on the step).
+        """Advance every mid-prefill slot (the padded path runs a slot's
+        whole prompt at once, in its admission step).  Overlap policy — the
+        double-buffered slot join: while other slots decode, a joining
+        prompt advances only ``prefill_chunks_per_step`` chunks per step so
+        admission never stalls the batch; with nothing decoding, the prompt
+        runs to completion now (nobody is waiting on the step).
 
         Returns the (slot_id, end, final) progress events of the chunks it
         dispatched; the caller flushes them to the backend AFTER the decode
@@ -599,6 +632,9 @@ class ContinuousScheduler:
         decode_live = self.decoding > 0
         for slot_id, slot in enumerate(self._slots):
             if slot is None or not slot.prefilling:
+                continue
+            if self._mode == "padded":
+                self._prefill_padded(slot_id)
                 continue
             if not slot.prefix_checked:
                 # shared-prefix adoption (EngineConfig.prefix_sharing; the
@@ -636,15 +672,11 @@ class ContinuousScheduler:
         if real < bucket:  # ragged tail: pad value is irrelevant (masked)
             tokens[real:] = slot.prompt[-1]
 
-        t0 = time.time()
         logits, cache = self._prefill_chunk(
             self.params, jnp.asarray(tokens[None]), self.backend.cache,
             jnp.int32(slot_id), jnp.int32(start), jnp.int32(real - 1),
         )
         self.backend.cache = cache
-        # dispatch-only timing: execution overlaps the decode step and is
-        # absorbed by whichever result is materialized first
-        self.stats["prefill_s"] += time.time() - t0
         self.stats["prefill_tokens"] += real
         self.stats["prefill_chunks"] += 1
         self._prefill_shapes.add(("bucket", bucket))
@@ -674,12 +706,10 @@ class ContinuousScheduler:
         padded = np.zeros(s, np.int32)
         padded[s - len(prompt):] = prompt  # left-pad (seed semantics)
 
-        t0 = time.time()
         logits, pcache = self._prefill(
             self.params, {"tokens": jnp.asarray(padded[None])}
         )
         logits = jax.block_until_ready(logits)
-        self.stats["prefill_s"] += time.time() - t0
         self.stats["prefill_tokens"] += s
         self._prefill_shapes.add(("padded", s))
         self.stats["prefill_compiles"] = len(self._prefill_shapes)
@@ -731,21 +761,22 @@ class ContinuousScheduler:
                 anchor[i] = slot.prefill_pos
         self.backend.sync_lens(self._lens, stage_anchor=anchor)
 
-        t0 = time.time()
         logits, cache = self._decode(
             self.params, jnp.asarray(tok), self.backend.cache
         )
         self.backend.cache = cache
-        nxt = sample_slots(jnp.stack(keys), draws, logits, self.cfg.sampler)
-        return nxt, t0
+        return sample_slots(jnp.stack(keys), draws, logits, self.cfg.sampler)
 
-    def _decode_commit(self, pending) -> None:
-        """Materialize the dispatched decode step and run its bookkeeping
-        (outputs, lengths, per-slot page traffic)."""
-        nxt_dev, t0 = pending
-        nxt = np.asarray(jax.block_until_ready(nxt_dev))
-        self.stats["decode_s"] += time.time() - t0
+    def _decode_commit(self, nxt_dev) -> None:
+        """Materialize the dispatched decode step (``serve.decode_wait``)
+        and run its bookkeeping (``serve.decode_commit``: outputs, lengths,
+        per-slot page traffic)."""
+        with steps.span("decode_wait"):
+            nxt = np.asarray(jax.block_until_ready(nxt_dev))
+        with steps.span("decode_commit"):
+            self._commit_tokens(nxt)
 
+    def _commit_tokens(self, nxt: np.ndarray) -> None:
         b = self.cfg.max_batch
         n_dec = self.decoding
         self.stats["decode_steps"] += 1
@@ -763,6 +794,7 @@ class ContinuousScheduler:
             if live:
                 committed.append((slot.req.rid, i))
             self.backend.on_decode_token(i, int(self._lens[i]))
+        steps.count("decoded", n_dec)
         if live and committed:
             # one shared stamp for the whole batch — the tokens
             # materialized together in one device step
@@ -805,8 +837,8 @@ class ContinuousScheduler:
         # memory-tier half (savings, evictions, engine-limited numbers) —
         # aggregated across the backend's tiers
         s.update(self.backend.report())
-        if s["decode_s"]:
-            s["decode_tok_per_s"] = s["decode_tokens"] / s["decode_s"]
+        s["host_s_by_phase"] = {p: ns * 1e-9
+                                for p, ns in self._phase_ns.items()}
         if s["decode_steps"]:
             s["mean_batch_occupancy"] = (
                 s["decode_batch_occupancy"] / s["decode_steps"]

@@ -5,6 +5,9 @@ lane timelines, and Perfetto/Prometheus exporters.
 the no-op :data:`NULL_COLLECTOR`, so a disabled serving path pays one
 branch per instrumentation site and stays bit-identical.  See
 :mod:`repro.telemetry.collector` for the event model.
+
+:mod:`repro.telemetry.steps` is always on: the phases and counters of each
+scheduler step, as profiler annotations and in a bounded step log.
 """
 
 from repro.telemetry.collector import (  # noqa: F401
@@ -23,3 +26,5 @@ from repro.telemetry.perfetto import (  # noqa: F401
     write_perfetto_trace,
 )
 from repro.telemetry.prometheus import prometheus_snapshot  # noqa: F401
+from repro.telemetry import steps  # noqa: F401
+from repro.telemetry.steps import PHASES, StepLog  # noqa: F401

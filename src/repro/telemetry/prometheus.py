@@ -7,12 +7,14 @@ collector ingests directly:
     repro_serving_decode_tokens_total 412
     repro_serving_ttft_wall_ns{quantile="p99"} 1.92e+07
     repro_serving_engine_utilization{tier="0"} 0.41
+    repro_serving_host_seconds_total{phase="engine_tick"} 0.87
 
 Scalar numbers from ``ContinuousScheduler.report()`` become gauges/counters
 (``*_total`` suffix for monotone counters), the telemetry latency quantiles
-become ``{quantile="..."}``-labelled series, and per-shard engine numbers
-are labelled by tier.  Nested non-numeric report entries are skipped — the
-snapshot is a metrics surface, not a serializer.
+become ``{quantile="..."}``-labelled series, per-shard engine numbers
+are labelled by tier, and the scheduler's host seconds per step phase
+(``host_s_by_phase``) by phase.  Nested non-numeric report entries are
+skipped — the snapshot is a metrics surface, not a serializer.
 """
 
 from __future__ import annotations
@@ -92,6 +94,12 @@ def prometheus_snapshot(report: dict, prefix: str = "repro_serving") -> str:
                 if key != "shard" and isinstance(value, (int, float)):
                     emit("shard_" + key, value, labels=f'{{tier="{tier}"}}',
                          kind="gauge")
+    phases = report.get("host_s_by_phase")
+    if isinstance(phases, dict):
+        for phase, secs in phases.items():
+            emit("host_seconds_total", secs, labels=f'{{phase="{phase}"}}',
+                 kind="counter",
+                 help_text="host seconds of scheduler steps, by phase")
     telem = report.get("telemetry")
     if isinstance(telem, dict):
         for key, value in telem.items():

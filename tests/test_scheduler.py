@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.core.quantization import PrecisionLadder
@@ -24,6 +25,7 @@ from repro.serving import (
     ServingEngine,
 )
 from repro.serving.kv_cache import PAGE_TOKENS, PageKey
+from repro.telemetry import PHASES
 
 
 # ---------------------------------------------------------------------------
@@ -222,13 +224,14 @@ def test_report_emits_per_1k_request_stats(smoke_model):
             for i in range(3)]
     eng.run(reqs)
     rep = eng.report()
-    for key in ("decode_tok_per_s", "kv_capacity_saving", "per_1k_requests",
+    for key in ("host_s_by_phase", "kv_capacity_saving", "per_1k_requests",
                 "decode_steps", "mean_batch_occupancy"):
         assert key in rep, key
     per = rep["per_1k_requests"]
     assert per["kv_stored_bytes"] > 0
     assert per["decode_tokens"] == pytest.approx(12 * 1000 / 3)  # 3 reqs x 4 tok
-    assert rep["decode_tok_per_s"] > 0
+    assert set(rep["host_s_by_phase"]) == set(PHASES)
+    assert rep["host_s_by_phase"]["decode_wait"] > 0
     assert 0 < rep["kv_capacity_saving"] < 1
 
 
@@ -553,3 +556,25 @@ def test_shed_latency_rejects_at_submit_with_reason(smoke_model):
     rep = sched.report()
     assert rep["requests_shed"] == 1
     assert rep["per_1k_requests"]["requests_shed"] > 0
+
+
+@pytest.mark.parametrize("device_kv", ["dense", "bitplane"])
+def test_programs_are_named_decode_and_prefill_chunk(device_kv):
+    """A fresh scheduler's decode and prefill-chunk jits lower to modules
+    named ``jit_decode`` and ``jit_prefill_chunk``: a profiler trace shows
+    those names with no renaming by the caller."""
+    cfg = get_config("smollm-135m", smoke=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    sched = ContinuousScheduler(model, params, EngineConfig(
+        max_batch=2, max_ctx=64, device_kv=device_kv))
+    cache = sched.backend.ensure_cache()
+    lowered = {
+        "jit_decode": sched._decode.lower(
+            params, jnp.zeros(2, jnp.int32), cache),
+        "jit_prefill_chunk": sched._prefill_chunk.lower(
+            params, jnp.zeros((1, 16), jnp.int32), cache, jnp.int32(0),
+            jnp.int32(0), jnp.int32(15)),
+    }
+    for name, low in lowered.items():
+        assert low.as_text().startswith(f"module @{name} "), name

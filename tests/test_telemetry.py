@@ -13,7 +13,11 @@ Pins the tentpole's contracts:
   same gate CI runs on the benchmark artifact) and the Prometheus snapshot
   renders counters/quantiles in exposition format;
 * ``aggregate_engine_reports`` pools per-step queue depths across shards
-  (fleet backlog percentiles) instead of max-ing per-shard percentiles.
+  (fleet backlog percentiles) instead of max-ing per-shard percentiles;
+* the always-on step log (``repro.telemetry.steps``): its phases nest in
+  ``serve.step`` in a profiler trace and fit inside their step's time, and
+  its counters equal what the engine serviced, what the backend copied to
+  the host, and what compiled.
 """
 
 import dataclasses
@@ -31,12 +35,15 @@ from repro.models.model import build_model
 from repro.serving import ContinuousScheduler, EngineConfig, Request
 from repro.telemetry import (
     NULL_COLLECTOR,
+    PHASES,
+    StepLog,
     TelemetryCollector,
     TelemetryConfig,
     build_trace_events,
     prometheus_snapshot,
     quantiles,
     validate_trace,
+    steps,
     write_perfetto_trace,
 )
 
@@ -399,3 +406,162 @@ def test_quantiles_nearest_rank():
     assert q == {"p50": 51.0, "p95": 95.0, "p99": 99.0,
                  "mean": 50.5, "max": 100.0, "count": 100}
     assert quantiles([])["count"] == 0
+
+
+# ---------------------------------------------------------------------------
+# Step spans and counters (repro.telemetry.steps)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def step_log(monkeypatch):
+    """A fresh step log for the test, in place of the process-wide one."""
+    log = StepLog()
+    monkeypatch.setattr(steps, "LOG", log)
+    return log
+
+
+def test_step_phases_nest_in_step_on_the_profiler_clock(smoke_model,
+                                                        tmp_path):
+    """Two steps under the profiler: every ``serve.<phase>`` lies inside a
+    ``serve.step`` on the thread of the annotation around them, so the
+    spans share the profiler's clock with everything else it records."""
+    from jax.profiler import ProfileData
+
+    model, params = smoke_model
+    sched = ContinuousScheduler(model, params, _cfg(telemetry=None))
+    sched.submit(Request(rid=0, prompt=_prompt(40), max_new_tokens=4))
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test.outer"):
+            sched.step()
+            sched.step()
+    path, = tmp_path.rglob("*.xplane.pb")
+    events = None
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n == "test.outer" for n, _, _ in evs):
+                events = evs
+    assert events is not None
+    step_spans = [e for e in events if e[0] == "serve.step"]
+    phases = [e for e in events
+              if e[0].startswith("serve.") and e[0] != "serve.step"]
+    assert len(step_spans) == 2
+    assert {n for n, _, _ in phases} == {"serve." + p for p in PHASES}
+    for n, s, e in phases:
+        assert any(s0 <= s and e <= e0 for _, s0, e0 in step_spans), n
+
+
+def test_phase_times_fit_inside_their_step(smoke_model, step_log):
+    model, params = smoke_model
+    _serve(model, params, _cfg(telemetry=None),
+           [_prompt(37), _prompt(80, 11), _prompt(24, 5)])
+    recs = list(step_log.records)
+    assert recs and step_log.dropped == 0
+    assert [r["step"] for r in recs] == list(range(len(recs)))
+    for r in recs:
+        assert set(r["phase_ns"]) <= set(PHASES)
+        assert sum(r["phase_ns"].values()) <= r["end_ns"] - r["start_ns"]
+    # a step that decoded ran every phase
+    assert any(set(r["phase_ns"]) == set(PHASES) for r in recs
+               if r["decoded"])
+    assert sum(r["decoded"] for r in recs) == 3 * 5
+
+
+@pytest.mark.parametrize("backend,shards", [("paged", 1), ("sharded", 2)])
+def test_memctl_jobs_equal_serviced_jobs(smoke_model, step_log, backend,
+                                         shards):
+    model, params = smoke_model
+    sched = ContinuousScheduler(model, params, _cfg(
+        backend=backend, shards=shards, telemetry=None, ladder=LADDER))
+
+    def serviced():
+        return sum(sum(t.engine.stats.serviced_jobs.values())
+                   for t in sched.backend.tiers)
+
+    before = serviced()
+    for i, p in enumerate([_prompt(37), _prompt(80, 11)]):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=6))
+    sched.run_until_drained()
+    jobs = sum(r["memctl_jobs"] for r in step_log.records)
+    assert jobs == serviced() - before > 0
+    assert step_log.records[-1]["memctl_queue"] == sched.backend.backlog()
+
+
+@pytest.mark.parametrize("device_kv", ["dense", "bitplane"])
+def test_d2h_bytes_equal_bytes_copied(smoke_model, step_log, device_kv):
+    model, params = smoke_model
+    sched = ContinuousScheduler(model, params, _cfg(
+        telemetry=None, device_kv=device_kv))
+    real = sched.backend.slot_kv_host
+    copied = []
+
+    def counting(*a, **kw):
+        k, v = real(*a, **kw)
+        copied.append(k.nbytes + v.nbytes)
+        return k, v
+
+    sched.backend.slot_kv_host = counting
+    for i, p in enumerate([_prompt(37), _prompt(20, 3)]):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=12))
+    sched.run_until_drained()
+    assert copied
+    assert sum(r["d2h_bytes"] for r in step_log.records) == sum(copied)
+
+
+def test_new_prefill_shape_compiles_in_its_own_step(step_log):
+    """A prompt that needs a prefill bucket no earlier request used
+    compiles in the step that dispatches it, and in no later step."""
+    cfg = get_config("smollm-135m", smoke=True)
+    model = build_model(cfg)  # a model of its own: no jit shared
+    params = model.init(jax.random.PRNGKey(0))
+    sched = ContinuousScheduler(model, params, EngineConfig(
+        max_batch=1, max_ctx=192, store_layers=2))
+    # 20 tokens: chunks of the 16 bucket; 24 new tokens fill a page
+    sched.submit(Request(rid=0, prompt=_prompt(20), max_new_tokens=24))
+    sched.run_until_drained()
+    n0 = len(step_log.records)
+    # 40 tokens: a 32 chunk, new; 4 new tokens fill no page
+    sched.submit(Request(rid=1, prompt=_prompt(40, 3), max_new_tokens=4))
+    sched.run_until_drained()
+    later = list(step_log.records)[n0:]
+    assert len(later) >= 2
+    assert later[0]["compiles"] >= 1 and later[0]["compile_s"] > 0
+    assert [r["compiles"] for r in later[1:]] == [0] * (len(later) - 1)
+
+
+def test_step_log_is_bounded_and_counts_overflow():
+    assert steps.LOG.records.maxlen == 8192
+    log = StepLog(maxlen=3)
+    for i in range(5):
+        log.append({"step": i})
+    assert [r["step"] for r in log.records] == [2, 3, 4]
+    assert log.dropped == 2
+
+
+def test_counters_outside_a_step_are_dropped(step_log):
+    steps.count("decoded", 3)  # no step open: nothing to add to
+    with steps.span("admit"):
+        pass
+    with steps.step(7) as rec:
+        steps.count("decoded", 3)
+        with steps.span("admit"):
+            pass
+    assert list(step_log.records) == [rec]
+    assert rec["step"] == 7 and rec["decoded"] == 3
+    assert set(rec["phase_ns"]) == {"admit"}
+
+
+def test_prometheus_exports_host_seconds_by_phase(smoke_model):
+    model, params = smoke_model
+    sched, _ = _serve(model, params, _cfg(telemetry=None), [_prompt(20)])
+    rep = sched.report()
+    assert set(rep["host_s_by_phase"]) == set(PHASES)
+    lines = prometheus_snapshot(rep).splitlines()
+    assert "# TYPE repro_serving_host_seconds_total counter" in lines
+    for phase in PHASES:
+        line, = [ln for ln in lines if ln.startswith(
+            f'repro_serving_host_seconds_total{{phase="{phase}"}} ')]
+        assert float(line.rsplit(" ", 1)[1]) == pytest.approx(
+            rep["host_s_by_phase"][phase])
