@@ -30,8 +30,11 @@ Protocol surface (what the scheduler calls — everything else is private):
                           — shard-scoped — and drops the request's pages)
 ``on_prefill_progress``   store newly completed prompt KV (pages + ragged
                           exact-length tail), assign ladder planes when done
-``on_decode_token``       store a filled decode page, re-rank the ladder,
-                          queue this step's decode-critical fetches
+``on_decode_tokens``      one decode step's tokens, every slot: copy the
+                          pages they filled to the host in one transfer,
+                          store them, re-rank the ladder, queue the step's
+                          decode-critical fetches (``on_decode_token`` is
+                          the one-slot form)
 ``tick/backlog``          service each tier's engine window / queued work
 ``admit_pressure_ns()``   engine-limited latency signal for admission
                           backpressure
@@ -47,8 +50,10 @@ from __future__ import annotations
 
 import abc
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+import functools
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -241,6 +246,35 @@ def make_fetch_job(store: CompressedKVStore, stats: Dict[str, float],
                seq_id=seq_key, size_fn=size)
 
 
+@functools.partial(jax.jit, static_argnames=("layers", "kv_shape"))
+def fill_pages(k, v, slots, rows, *, layers: int, kv_shape):
+    """The decode pages a step filled, every slot's at once: K and V of
+    the first ``layers`` layers at device ``rows`` (n, 16) of ``slots``
+    (n,), as one (2, n, layers, 16, Hkv*hd) array for one device->host
+    transfer.  The layout of ``k``/``v`` picks the body: dense caches
+    (L, B, S, Hkv, hd) are gathered; bit-plane caches (L, bits, B, S/16,
+    1, W) gather each row's page (``rows[:, 0] // 16``: a filled page is
+    page-aligned) and unpack it at full precision, a bf16 bitcast, so the
+    result is bitwise what ``slot_kv_host`` copies.  One program per
+    (layout, layers, n, cache shape)."""
+    n = slots.shape[0]
+
+    def gather(c):
+        if c.ndim == 6:
+            from repro.kernels.paged_attention.ref import unpack_kv_ref
+
+            bits = c.shape[1]
+            pl = c[:layers, :, slots, rows[:, 0] // PAGE_TOKENS]
+            # (ls, bits, n, 1, W) -> (bits, ls, n, 1 page, 1, W)
+            pl = jnp.moveaxis(pl, 1, 0)[..., None, :, :]
+            dense = unpack_kv_ref(pl, bits, kv_shape, bits)
+        else:
+            dense = c[:layers][:, slots[:, None], rows]
+        return jnp.moveaxis(dense.reshape(layers, n, PAGE_TOKENS, -1), 1, 0)
+
+    return jnp.stack([gather(k), gather(v)])
+
+
 class KVBackend(abc.ABC):
     """Base implementation of the protocol: single-tier, full-attention,
     paged.  Subclasses override the routing/layout hooks (``_page_targets``,
@@ -263,6 +297,8 @@ class KVBackend(abc.ABC):
         self.tiers: List[MemTier] = self._build_tiers(controller)
         self._cache = None
         self._slots: Dict[int, SlotState] = {}
+        #: ``fill_pages`` has run once for this cache (compiled or loaded)
+        self._fill_pages_ready = False
         # weight streaming (ISSUE 9): one streamer per tier, built by
         # attach_weights; empty under weight_stream='resident'
         if cfg.weight_stream not in ("resident", "compressed"):
@@ -479,7 +515,7 @@ class KVBackend(abc.ABC):
         ``layers`` overrides the layer count (prefix-index snapshots copy
         ALL layers: adoption rebuilds the whole device column, not just the
         compressed-store's capped subset).  The copied bytes go to the
-        step's ``d2h_bytes`` counter."""
+        step's ``d2h_bytes`` counter and each transfer to ``d2h_copies``."""
         k, v = self._copy_kv_host(slot_id, t0, t1, layers)
         steps.count("d2h_bytes", k.nbytes + v.nbytes)
         return k, v
@@ -500,9 +536,11 @@ class KVBackend(abc.ABC):
                 pl = jnp.moveaxis(self._cache[name][:ls, :, slot_id], 1, 0)
                 dense = read_kv_rows(pl, rows, self._kv_shape())
                 out.append(np.asarray(dense.reshape(ls, t, -1)))
+            steps.count("d2h_copies", 2)
             return tuple(out)
         k = np.asarray(self._cache["k"][:ls, slot_id, rows], np.float32)
         v = np.asarray(self._cache["v"][:ls, slot_id, rows], np.float32)
+        steps.count("d2h_copies", 2)
         st = self._slots.get(slot_id)
         if "sk" in self._cache and st is not None:
             # staged decode: tokens >= stage_base still live in the staging
@@ -514,6 +552,7 @@ class KVBackend(abc.ABC):
                     self._cache["sk"][:ls, slot_id, tok - sb], np.float32)
                 v[:, tok - t0] = np.asarray(
                     self._cache["sv"][:ls, slot_id, tok - sb], np.float32)
+                steps.count("d2h_copies", 2)
         return (k.reshape(ls, t, -1).astype(ml_dtypes.bfloat16),
                 v.reshape(ls, t, -1).astype(ml_dtypes.bfloat16))
 
@@ -521,6 +560,11 @@ class KVBackend(abc.ABC):
     def bind_slot(self, slot_id: int, rid: int) -> None:
         self._slots[slot_id] = SlotState(rid=rid)
         self._reset_device_planes(slot_id)
+        if not self._fill_pages_ready and self._batched_fills():
+            # compile (or load) the page-fill copy before any decode page
+            # fills, so no serving step after admission compiles it
+            jax.block_until_ready(self._run_fill_pages([]))
+            self._fill_pages_ready = True
 
     def _reset_device_planes(self, slot_id: int) -> None:
         """Bit-plane layout: a reused slot must not inherit the previous
@@ -732,10 +776,73 @@ class KVBackend(abc.ABC):
             self._register_prefix(slot_id, end)
 
     def on_decode_token(self, slot_id: int, ln: int) -> None:
-        """One decode token landed at position ln-1: store the page if it
-        just filled (and re-rank the ladder), then queue this step's
-        decode-critical fetch traffic for the slot."""
+        """One slot's form of :meth:`on_decode_tokens`."""
+        self.on_decode_tokens([(slot_id, ln)])
+
+    def on_decode_tokens(self, tokens: Sequence[Tuple[int, int]]) -> None:
+        """One decode step's tokens, ``(slot, ln)`` each: the token landed
+        at position ln-1.  First the pages that just filled (ln a multiple
+        of 16) go to the host together, one compiled gather and one
+        transfer (:meth:`_copy_fill_pages`); then, slot by slot in the
+        given order, each filled page is stored (and the ladder
+        re-ranked) and the step's decode-critical fetch traffic queued.
+        The device K/V does not change between the two stages, so the jobs
+        and bytes are those of a per-slot copy in the same order."""
         self._note_compute()
+        fills = ([(s, ln) for s, ln in tokens if ln % PAGE_TOKENS == 0]
+                 if self._batched_fills() else [])
+        pages = self._copy_fill_pages(fills) if fills else {}
+        for slot_id, ln in tokens:
+            self._commit_decode_token(slot_id, ln, pages.get(slot_id))
+
+    def _batched_fills(self) -> bool:
+        """Whether decode page fills take :func:`fill_pages`.  Staged decode
+        (``'sk'`` in the cache) keeps the per-slot ``slot_kv_host`` copy:
+        a filled page's newest rows may sit in the staging ring, read at
+        the staging base the slot's own commit advances first."""
+        return (self.cfg.store_kv_compressed and self._cache is not None
+                and "sk" not in self._cache)
+
+    def _copy_fill_pages(self, fills: Sequence[Tuple[int, int]]
+                         ) -> Dict[int, tuple]:
+        """Host copies (K, V), each (L_stored, 16, channels) bf16, of the
+        page [ln-16, ln) of every ``(slot, ln)`` in ``fills``: bitwise what
+        ``slot_kv_host(slot, ln-16, ln)`` returns, in one transfer."""
+        import ml_dtypes
+
+        dev = self._run_fill_pages(fills)
+        out = np.asarray(dev)
+        if out.dtype != ml_dtypes.bfloat16:
+            # the rounding slot_kv_host's dense copy applies on the host
+            out = out.astype(np.float32).astype(ml_dtypes.bfloat16)
+        steps.count("d2h_bytes", dev.nbytes)
+        steps.count("d2h_copies", 1)
+        steps.count("fill_pages", len(fills))
+        return {s: (out[0, j], out[1, j]) for j, (s, _) in enumerate(fills)}
+
+    def _run_fill_pages(self, fills: Sequence[Tuple[int, int]]):
+        """Dispatch :func:`fill_pages` for ``fills`` padded to ``max_batch``
+        entries (the padding reads slot 0's first page, and is dropped), so
+        every step runs the one program of this cache's shapes."""
+        slots = np.zeros(self.cfg.max_batch, np.int32)
+        rows = np.tile(np.arange(PAGE_TOKENS, dtype=np.int32),
+                       (self.cfg.max_batch, 1))
+        for j, (s, ln) in enumerate(fills):
+            r = self._device_rows(ln - PAGE_TOKENS, ln)
+            slots[j] = s
+            rows[j] = (np.arange(r.start, r.stop) if isinstance(r, slice)
+                       else r)
+        names = (("k_planes", "v_planes") if self.device_kv == "bitplane"
+                 else ("k", "v"))
+        return fill_pages(*(self._cache[n] for n in names), slots, rows,
+                          layers=self.stored_layers(),
+                          kv_shape=self._kv_shape())
+
+    def _commit_decode_token(self, slot_id: int, ln: int,
+                             page: Optional[tuple]) -> None:
+        """Store the slot's page if it just filled (``page``: its host copy,
+        None to copy it here) and re-rank the ladder, then queue the
+        step's decode-critical fetch traffic for the slot."""
         st = self._slots[slot_id]
         ws = self.mcfg.decode_staging
         if ws > 0 and ln - st.stage_base >= ws:
@@ -746,7 +853,7 @@ class KVBackend(abc.ABC):
             return
         self._expire_dead_pages(st, ln)
         if ln % PAGE_TOKENS == 0:  # a decode page just filled
-            self._write_span(slot_id, ln - PAGE_TOKENS, ln)
+            self._write_span(slot_id, ln - PAGE_TOKENS, ln, page)
             st.stored_tokens = ln
             self._assign_ladder_planes(slot_id, ln)
         self._account_step_fetch(slot_id, ln)
@@ -771,12 +878,15 @@ class KVBackend(abc.ABC):
         page that was never stored is not phantom-fetched."""
         return st.live_from_page, -(-st.stored_tokens // PAGE_TOKENS)
 
-    def _write_span(self, slot_id: int, t0: int, t1: int) -> None:
+    def _write_span(self, slot_id: int, t0: int, t1: int,
+                    copied: Optional[tuple] = None) -> None:
         """Page-split device KV rows [t0, t1) (t0 page-aligned; a ragged t1
         becomes an exact-length tail page) and queue one write job per page
-        per stream per stored layer on the owning tier(s)."""
+        per stream per stored layer on the owning tier(s).  ``copied`` is
+        the rows' host (K, V) when the caller already made it."""
         st = self._slots[slot_id]
-        k_np, v_np = self.slot_kv_host(slot_id, t0, t1)
+        k_np, v_np = (self.slot_kv_host(slot_id, t0, t1) if copied is None
+                      else copied)
         first_page = t0 // PAGE_TOKENS
         for li in range(k_np.shape[0]):
             for stream, kv in (("k", k_np[li]), ("v", v_np[li])):

@@ -783,6 +783,7 @@ class ContinuousScheduler:
         self.stats["decode_batch_occupancy"] += n_dec / b
         live = self.telemetry.enabled
         committed: List[tuple] = []
+        tokens: List[tuple] = []
         for i, slot in enumerate(self._slots):
             if slot is None or slot.prefilling:
                 continue
@@ -793,7 +794,8 @@ class ContinuousScheduler:
             self.stats["decode_tokens"] += 1
             if live:
                 committed.append((slot.req.rid, i))
-            self.backend.on_decode_token(i, int(self._lens[i]))
+            tokens.append((i, int(self._lens[i])))
+        self.backend.on_decode_tokens(tokens)
         steps.count("decoded", n_dec)
         if live and committed:
             # one shared stamp for the whole batch — the tokens

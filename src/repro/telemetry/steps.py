@@ -27,7 +27,11 @@ Counters (zero unless the step did the work):
 * ``decoded``: tokens committed;
 * ``memctl_jobs``: memctl jobs serviced in the step's engine tick, summed
   over tiers; ``memctl_queue``: jobs still queued after it;
-* ``d2h_bytes``: bytes the backend copied device->host (``slot_kv_host``);
+* ``d2h_bytes``: bytes the backend copied device->host (``slot_kv_host``
+  and the batched decode page-fill copy); ``d2h_copies``: the transfers
+  that moved them;
+* ``fill_pages``: decode pages the batched page-fill copy served, one per
+  slot whose page filled;
 * ``compiles`` / ``compile_s``: XLA compile requests during the step and
   their seconds, from JAX's ``backend_compile_duration`` event (a load
   from the persistent compile cache is a request too).
@@ -49,7 +53,7 @@ import jax
 PHASES = ("admit", "prefill_dispatch", "decode_dispatch", "kv_flush",
           "decode_wait", "decode_commit", "engine_tick", "retire")
 COUNTERS = ("decoded", "memctl_jobs", "memctl_queue", "d2h_bytes",
-            "compiles", "compile_s")
+            "d2h_copies", "fill_pages", "compiles", "compile_s")
 PREFIX = "serve."
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

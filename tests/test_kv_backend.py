@@ -750,3 +750,184 @@ def test_ring_weight_stream_tokens_bit_identical(ring_model):
 def test_weight_stream_rejects_unknown_mode(smoke_model):
     with pytest.raises(ValueError, match="weight_stream"):
         make_backend(smoke_model[0], _cfg("paged", 1, weight_stream="mmap"))
+
+
+# ---------------------------------------------------------------------------
+# Batched decode page-fill copy (one compiled gather, one transfer a step)
+# ---------------------------------------------------------------------------
+
+
+def _fill_cfg(backend, device_kv, **kw):
+    if backend == "ring":
+        return EngineConfig(max_batch=3, max_ctx=96, backend="ring",
+                            store_layers=2, device_kv=device_kv, **kw)
+    return _cfg(backend, 2 if backend == "sharded" else 1,
+                device_kv=device_kv, **kw)
+
+
+@pytest.mark.parametrize("filling", ["one", "all"])
+@pytest.mark.parametrize("backend", ["paged", "ring", "sharded"])
+@pytest.mark.parametrize("device_kv", ["dense", "bitplane"])
+def test_batched_fill_copy_is_bitwise_slot_kv_host(
+        smoke_model, ring_model, device_kv, backend, filling):
+    """The batched page-fill copy returns, for each slot it is given,
+    bitwise the K and V ``slot_kv_host(slot, ln-16, ln)`` copies — on both
+    device layouts, through paged, ring (pages folded into the window)
+    and two-shard backends, for one slot of the batch or all of them in
+    an order of their own."""
+    model, params = ring_model if backend == "ring" else smoke_model
+    sched = ContinuousScheduler(model, params,
+                                _fill_cfg(backend, device_kv))
+    for i, n in enumerate([37, 50, 29]):
+        sched.submit(Request(rid=i, prompt=_prompt(n, i),
+                             max_new_tokens=60))
+    be = sched.backend
+    lens = {}
+    while len(lens) < 3 or min(lens.values()) < 40:
+        sched.step()
+        lens = {i: int(sched._lens[i]) for i, slot in enumerate(sched._slots)
+                if slot is not None and not slot.prefilling}
+    fills = [(s, ln // PAGE_TOKENS * PAGE_TOKENS)
+             for s, ln in sorted(lens.items(), reverse=True)]
+    if filling == "one":
+        fills = fills[1:2]
+    got = be._copy_fill_pages(fills)
+    assert sorted(got) == sorted(s for s, _ in fills)
+    for s, ln in fills:
+        want = be.slot_kv_host(s, ln - PAGE_TOKENS, ln)
+        for g, w in zip(got[s], want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.any(w != 0)
+            np.testing.assert_array_equal(g.view(np.uint16),
+                                          w.view(np.uint16))
+
+
+@pytest.mark.parametrize("backend,device_kv", [
+    ("paged", "dense"), ("paged", "bitplane"), ("sharded", "bitplane"),
+    ("ring", "bitplane")])
+def test_batched_fill_job_stream_matches_per_slot_copy(
+        smoke_model, ring_model, monkeypatch, backend, device_kv):
+    """Pages filling in the same step and in different steps: the memctl
+    job stream (tier, class, key, bytes, scope, in order), the backend's
+    ``report()`` and the served tokens equal those of a run whose slots
+    commit one by one, each copying its filled page through
+    ``slot_kv_host``.  The step log counts each fill in ``fill_pages``, a
+    step's fills add one transfer to ``d2h_copies``, and no step from the
+    first fill on compiles the copy."""
+    from repro.memctl.runtime import CompressionEngineRuntime
+    from repro.serving.backends.base import KVBackend, fill_pages
+    from repro.telemetry import StepLog, steps
+
+    model, params = ring_model if backend == "ring" else smoke_model
+    # the ladder re-ranks at every fill, and a store budget that evicts
+    # re-activates pages through slot_kv_host; both cost the bit-plane
+    # layout's eager unpack a minute in the interpreter, so the dense case
+    # carries both and the bit-plane paged cases neither
+    ladder = {("ring", "bitplane"): PrecisionLadder([(1, 16), (-1, 4)]),
+              ("paged", "dense"): LADDER}.get((backend, device_kv))
+    budget = 16 * 1024 if device_kv == "dense" else None
+    jobs = []
+    real_submit = CompressionEngineRuntime.submit
+
+    def submit(engine, job):
+        jobs.append((engine.tier, job.klass, job.key, job.nbytes,
+                     job.seq_id))
+        return real_submit(engine, job)
+
+    monkeypatch.setattr(CompressionEngineRuntime, "submit", submit)
+
+    def run(spy):
+        jobs.clear()
+        sched = ContinuousScheduler(model, params, _fill_cfg(
+            backend, device_kv, ladder=ladder, max_stored_bytes=budget))
+        be = sched.backend
+        fills, copies, sizes = {}, {}, []
+        if spy:
+            real_tokens, real_host = be.on_decode_tokens, be.slot_kv_host
+
+            def on_decode_tokens(tokens):
+                n = sum(ln % PAGE_TOKENS == 0 for _, ln in tokens)
+                fills[sched.step_count] = n
+                if n:
+                    sizes.append(fill_pages._cache_size())
+                real_tokens(tokens)
+
+            def slot_kv_host(*a, **kw):
+                copies[sched.step_count] = copies.get(sched.step_count, 0) + 1
+                return real_host(*a, **kw)
+
+            be.on_decode_tokens = on_decode_tokens
+            be.slot_kv_host = slot_kv_host
+        reqs = [Request(rid=i, prompt=_prompt(n, i), max_new_tokens=40)
+                for i, n in enumerate([32, 48, 37])]
+        for r in reqs:
+            sched.submit(r)
+        sched.run_until_drained()
+        assert all(r.done for r in reqs)
+        return (list(jobs), be.report(), [r.output for r in reqs],
+                fills, copies, sizes)
+
+    log = StepLog()
+    monkeypatch.setattr(steps, "LOG", log)
+    jobs_b, rep_b, out_b, fills, copies, sizes = run(spy=True)
+    recs = list(log.records)
+
+    def per_slot(be, tokens):
+        # each slot's commit copies its own filled page (slot_kv_host)
+        be._note_compute()
+        for s, ln in tokens:
+            be._commit_decode_token(s, ln, None)
+
+    monkeypatch.setattr(KVBackend, "on_decode_tokens", per_slot)
+    jobs_p, rep_p, out_p, *_ = run(spy=False)
+
+    assert out_b == out_p
+    assert jobs_b == jobs_p
+    assert any(j[1] == JobClass.KV_WRITE for j in jobs_b)
+    assert rep_b["kv_evictions"] > 0 or budget is None
+    assert rep_b == rep_p
+    # the trace fills pages both together and alone
+    assert max(fills.values()) >= 2 and 1 in fills.values()
+    assert sum(r["fill_pages"] for r in recs) == sum(fills.values())
+    for r in recs:
+        n = fills.get(r["step"], 0)
+        assert r["fill_pages"] == n
+        assert r["d2h_copies"] == 2 * copies.get(r["step"], 0) + (n > 0)
+    assert sizes and sizes[0] >= 1 and set(sizes) == {
+        fill_pages._cache_size()}
+
+
+def test_staged_decode_fills_copy_slot_by_slot(smoke_model, monkeypatch):
+    """Staged decode keeps the per-slot copy: a filled page's newest rows
+    may still sit in the staging ring.  Every decode fill is copied by
+    ``slot_kv_host`` at [ln-16, ln), and the batched copy serves none."""
+    from repro.telemetry import StepLog, steps
+
+    _, params = smoke_model
+    model_st = build_model(dataclasses.replace(
+        get_config("smollm-135m", smoke=True), decode_staging=4))
+    log = StepLog()
+    monkeypatch.setattr(steps, "LOG", log)
+    sched = ContinuousScheduler(model_st, params,
+                                _cfg("paged", 1, device_kv="dense"))
+    be = sched.backend
+    spans, fills = [], []
+    real_tokens, real_host = be.on_decode_tokens, be.slot_kv_host
+
+    def on_decode_tokens(tokens):
+        fills.extend((s, ln - PAGE_TOKENS, ln) for s, ln in tokens
+                     if ln % PAGE_TOKENS == 0)
+        real_tokens(tokens)
+
+    def slot_kv_host(s, t0, t1, **kw):
+        spans.append((s, t0, t1))
+        return real_host(s, t0, t1, **kw)
+
+    be.on_decode_tokens = on_decode_tokens
+    be.slot_kv_host = slot_kv_host
+    for i, n in enumerate([20, 27]):
+        sched.submit(Request(rid=i, prompt=_prompt(n, i), max_new_tokens=30))
+    sched.run_until_drained()
+    assert not be._batched_fills()
+    assert fills and set(fills) <= set(spans)
+    assert sum(r["fill_pages"] for r in log.records) == 0

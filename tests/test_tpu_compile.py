@@ -121,3 +121,23 @@ def test_dense_decode_step_compiles_for_v5e(model, one_chip):
     # the dense step has no kernel; it must fit one chip's 16 GB
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("bitplane", [True, False])
+def test_fill_page_copy_compiles_for_v5e(model, one_chip, bitplane):
+    """The decode page-fill copy of every slot at once (4 stored layers):
+    gather (and on the bit-plane layout, unpack) into one bf16 array of
+    2 x B x 4 x 16 x (Hkv*hd)."""
+    from repro.serving.backends.base import fill_pages
+
+    cfg = model.cfg
+    _, _, cache = _decode_args(model, one_chip, bitplane)
+    names = ("k_planes", "v_planes") if bitplane else ("k", "v")
+    idx = _on(one_chip, (jax.ShapeDtypeStruct((B,), jnp.int32),
+                         jax.ShapeDtypeStruct((B, PAGE), jnp.int32)))
+    compiled = fill_pages.lower(
+        cache[names[0]], cache[names[1]], *idx, layers=4,
+        kv_shape=(cfg.n_kv_heads, cfg.head_dim)).compile()
+    out = compiled.out_info
+    assert out.shape == (2, B, 4, PAGE, cfg.n_kv_heads * cfg.head_dim)
+    assert out.dtype == jnp.bfloat16
