@@ -1,67 +1,21 @@
-"""A configuration file's architecture, and the weights every run draws from
-its seed.
+"""The weights every run draws from its seed, for any architecture.
 
 Configuration files keep the public ``config.json`` keys of the model
 (``hidden_size``, ``num_hidden_layers`` ...), so a key changed from the
-source is visible by name.  ``Arch`` is the handful of sizes the weights, the
-reference and the cost functions need.  The weights are drawn on the device
-in one jitted call, in the dtype they are served in, and laid out as the
+source is visible by name.  The architecture file that the configuration's
+``model_type`` names (``bench/archs/<model_type>.py``) parses them into the
+handful of sizes the weights, the reference and the cost functions need,
+and lays out the parameter tree.  The weights are drawn on the device in
+one jitted call, in the dtype they are served in, and laid out as the
 serving program takes them; the reference draws the same weights again from
 the same seed instead of taking the program's arrays.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import numpy as np
-
-
-@dataclasses.dataclass(frozen=True)
-class Arch:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-    tie_embeddings: bool
-    dtype: str
-
-    @classmethod
-    def from_config(cls, cfg: dict) -> "Arch":
-        if cfg.get("model_type") != "llama" or cfg.get("hidden_act") != "silu":
-            raise ValueError("only llama-architecture configurations with a "
-                             "silu-gated MLP are described here")
-        d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-        return cls(
-            n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
-            n_kv_heads=cfg["num_key_value_heads"],
-            head_dim=cfg.get("head_dim", d // h),
-            d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-            rope_theta=float(cfg["rope_theta"]),
-            norm_eps=float(cfg["rms_norm_eps"]),
-            tie_embeddings=bool(cfg["tie_word_embeddings"]),
-            dtype=cfg["serving"]["dtype"],
-        )
-
-    def layer_params(self) -> int:
-        d, hd = self.d_model, self.head_dim
-        attn = d * hd * (2 * self.n_heads + 2 * self.n_kv_heads)
-        return attn + 3 * d * self.d_ff + 2 * d
-
-    def head_params(self) -> int:
-        """Parameters of the output projection (the tied table counts once
-        here and is read whole by every step's logits)."""
-        return self.vocab * self.d_model
-
-    def kv_bytes_per_token(self) -> int:
-        """bf16 K and V of one token over all layers."""
-        return self.n_layers * 2 * self.n_kv_heads * self.head_dim * 2
 
 
 def seed_key(seed: int):
@@ -71,28 +25,6 @@ def seed_key(seed: int):
 
     words = np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
     return jnp.asarray(words, jnp.uint32)
-
-
-def weight_shapes(a: Arch) -> dict:
-    """The serving program's parameter tree as {path: (shape, fan_in)};
-    norm scales have fan_in 0."""
-    L, d, hd, ff = a.n_layers, a.d_model, a.head_dim, a.d_ff
-    s = {
-        "embed/table": ((a.vocab, d), d),
-        "layers/ln1/scale": ((L, d), 0),
-        "layers/attn/wq": ((L, d, a.n_heads, hd), d),
-        "layers/attn/wk": ((L, d, a.n_kv_heads, hd), d),
-        "layers/attn/wv": ((L, d, a.n_kv_heads, hd), d),
-        "layers/attn/wo": ((L, a.n_heads, hd, d), a.n_heads * hd),
-        "layers/ln2/scale": ((L, d), 0),
-        "layers/mlp/w_gate": ((L, d, ff), d),
-        "layers/mlp/w_in": ((L, d, ff), d),
-        "layers/mlp/w_out": ((L, ff, d), ff),
-        "final_norm/scale": ((d,), 0),
-    }
-    if not a.tie_embeddings:
-        s["lm_head/w"] = ((a.vocab, d), d)
-    return s
 
 
 def _nest(flat: dict) -> dict:
@@ -107,11 +39,11 @@ def _nest(flat: dict) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _generator(a: Arch):
+def _generator(arch_module, a):
     import jax
     import jax.numpy as jnp
 
-    shapes = weight_shapes(a)
+    shapes = arch_module.weight_shapes(a)
     dtype = jnp.dtype(a.dtype)
 
     def make(key):
@@ -128,7 +60,8 @@ def _generator(a: Arch):
     return jax.jit(make)
 
 
-def make_weights(a: Arch, seed: int) -> dict:
+def make_weights(arch_module, a, seed: int) -> dict:
     """All weights of the configuration from ``seed``, on the default
-    device, in one jitted call."""
-    return _generator(a)(seed_key(seed))
+    device, in one jitted call: ``arch_module`` is its architecture file
+    and ``a`` what that file's ``parse`` made of the configuration."""
+    return _generator(arch_module, a)(seed_key(seed))

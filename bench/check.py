@@ -18,7 +18,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from bench.arch import Arch, make_weights
+from bench.arch import make_weights
 
 PAGE = 16
 
@@ -69,16 +69,16 @@ def sequences(records) -> List[tuple]:
              r.prompt_len, r) for r in records]
 
 
-def widest_gap(config: dict, seed: int, records,
+def widest_gap(config: dict, arch_module, arch, seed: int, records,
                control: bool = False) -> tuple:
     """(widest gap, served tokens compared) over the sampled requests —
     of the served tokens, or with ``control`` of the fp8 forward's own
-    picks at the same positions."""
-    arch = Arch.from_config(config)
+    picks at the same positions.  ``arch_module`` is the configuration's
+    architecture file and ``arch`` its ``parse`` of the configuration."""
     ref = reference_module(config)
     bitplane = config["serving"]["device_kv"] == "bitplane"
     levels = plane_levels(config)
-    w = make_weights(arch, seed)
+    w = make_weights(arch_module, arch, seed)
     widest, count = 0.0, 0
     for tokens, p, rec in sequences(records):
         rows = keep_rows(rec, len(tokens)) if bitplane else None

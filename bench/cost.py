@@ -6,24 +6,29 @@ step's plane map keeps.  Padding, plane-unit rounding and pages past a
 slot's length are not counted, so a share computed from these rises when a
 change removes waste.  Necessary operations are two per weight per token
 plus the attention's products over the live tokens.
+
+Each architecture file (``bench/archs/<model_type>.py``) counts its own
+weights, operations and bytes of KV a token; what is shared by every
+architecture is here.
 """
 
 from __future__ import annotations
 
-from typing import Iterable, Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
-from bench.arch import Arch
-
 PAGE = 16
 
+#: bytes of one element of each served dtype
+DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
-def kv_bytes(a: Arch, live: int, row: Optional[np.ndarray],
+
+def kv_bytes(per_token: int, live: int, row: Optional[np.ndarray],
              bits: int = 16) -> float:
-    """K and V bytes of ``live`` tokens over all layers at the planes the
-    map ``row`` keeps per 16-token page (None: every plane)."""
-    per_token = a.kv_bytes_per_token()
+    """KV bytes of ``live`` tokens at ``per_token`` bytes each (all layers)
+    at the planes the map ``row`` keeps per 16-token page (None: every
+    plane)."""
     if row is None:
         return float(live * per_token)
     pages = -(-live // PAGE)
@@ -31,30 +36,3 @@ def kv_bytes(a: Arch, live: int, row: Optional[np.ndarray],
     tokens[-1] = live - PAGE * (pages - 1)
     keep = np.asarray(row[:pages], np.float64)
     return float(per_token * np.sum(tokens * keep) / bits)
-
-
-def weight_bytes(a: Arch) -> float:
-    """Every layer's weights and the output projection, once, in the served
-    dtype."""
-    width = {"bfloat16": 2, "float16": 2, "float32": 4}[a.dtype]
-    return float(width * (a.n_layers * a.layer_params() + a.head_params()))
-
-
-def decode_flops(a: Arch, lives: Iterable[int]) -> float:
-    lives = list(lives)
-    d, hd = a.d_model, a.head_dim
-    matmul = a.n_layers * (d * hd * (2 * a.n_heads + 2 * a.n_kv_heads)
-                           + 3 * d * a.d_ff) + a.head_params()
-    attn = 4 * a.n_layers * a.n_heads * hd * sum(lives)
-    return float(2 * matmul * len(lives) + attn)
-
-
-def decode_step_need(a: Arch, decoded: Iterable[Tuple[int, int, object]],
-                     bitplane: bool) -> Tuple[float, float, float]:
-    """(flops, bytes, kv bytes) one decode step needs for the requests it
-    served: ``decoded`` holds (rid, live tokens, plane-map row)."""
-    decoded = list(decoded)
-    kv = sum(kv_bytes(a, live, row if bitplane else None)
-             for _, live, row in decoded)
-    flops = decode_flops(a, [live for _, live, _ in decoded])
-    return flops, weight_bytes(a) + kv, kv

@@ -28,12 +28,13 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Optional
 
 import numpy as np
 
 from bench import check, device, probe, spec
-from bench.arch import Arch, make_weights
+from bench.arch import make_weights
 from bench.client import Client
 from bench.traffic import Generator
 
@@ -47,7 +48,9 @@ class Run:
     """What the metric readers read."""
 
     cell: spec.Cell
-    arch: Arch
+    #: the configuration's architecture file, and its ``parse`` result
+    arch_module: ModuleType
+    arch: object
     bitplane: bool
     setup_s: float
     client: Client
@@ -55,30 +58,20 @@ class Run:
     peaks: dict
 
 
-def program_config(config: dict, arch: Arch):
-    """The serving program's model and engine configurations."""
-    from repro.configs.base import ModelConfig
+def engine_config(config: dict):
+    """The serving program's engine configuration."""
     from repro.core.quantization import PrecisionLadder
     from repro.serving import EngineConfig
 
-    model = ModelConfig(
-        name=config["name"], family="dense", n_layers=arch.n_layers,
-        d_model=arch.d_model, n_heads=arch.n_heads,
-        n_kv_heads=arch.n_kv_heads, head_dim=arch.head_dim, d_ff=arch.d_ff,
-        vocab=arch.vocab, act="swiglu", rope_theta=arch.rope_theta,
-        norm_eps=arch.norm_eps, tie_embeddings=arch.tie_embeddings,
-        dtype=arch.dtype,
-    )
     s = config["serving"]
     ladder = s.get("ladder")
-    engine = EngineConfig(
+    return EngineConfig(
         max_batch=s["max_batch"], max_ctx=s["max_ctx"], backend="paged",
         device_kv=s["device_kv"], decode_kernel=s.get("decode_kernel",
                                                       "fused"),
         ladder=PrecisionLadder([tuple(r) for r in ladder]) if ladder else None,
         prefix_sharing=False, weight_stream="resident",
     )
-    return model, engine
 
 
 #: request ids of the warm-up requests, clear of the traffic's (from 0)
@@ -141,13 +134,16 @@ def run(args, t_start: float, root: Path = spec.ROOT, chips: bool = True,
     from repro.serving import ContinuousScheduler, Request
 
     cfg, traffic = cell.config, cell.traffic
-    arch = Arch.from_config(cfg)
+    arch_module = spec.arch_module(cfg["model_type"], root)
+    arch = arch_module.parse(cfg)
     bitplane = cfg["serving"]["device_kv"] == "bitplane"
     log(f"[setup] {dev['kind']} x{dev['count']}, compile cache {cache_dir}")
-    params = jax.block_until_ready(make_weights(arch, args.seed))
+    params = jax.block_until_ready(make_weights(arch_module, arch,
+                                                args.seed))
     t_w = time.perf_counter()
-    model_cfg, engine_cfg = program_config(cfg, arch)
-    sched = ContinuousScheduler(build_model(model_cfg), params, engine_cfg)
+    sched = ContinuousScheduler(
+        build_model(arch_module.model_config(cfg, arch)), params,
+        engine_config(cfg))
     probe.name_programs(sched)
     max_batch = cfg["serving"]["max_batch"]
     warm_up(sched, traffic, arch.vocab, max_batch)
@@ -209,13 +205,15 @@ def run(args, t_start: float, root: Path = spec.ROOT, chips: bool = True,
     del sched, params
     gc.collect()
     t_ref = time.perf_counter()
-    gap, n_tok = check.widest_gap(cfg, args.seed, sampled)
+    gap, n_tok = check.widest_gap(cfg, arch_module, arch, args.seed,
+                                  sampled)
     limit = check.limit(cfg)
     log(f"[check] reference over {len(sampled)} requests, {n_tok} served "
         f"tokens in {time.perf_counter() - t_ref:.3f} s")
     checks = {}
     if control:
-        cgap, _ = check.widest_gap(cfg, args.seed, sampled, control=True)
+        cgap, _ = check.widest_gap(cfg, arch_module, arch, args.seed,
+                                   sampled, control=True)
         checks["control_logit_gap"] = {"value": cgap, "limit": limit}
     checks.update({
         "max_logit_gap": {"value": gap, "limit": limit},
@@ -226,7 +224,7 @@ def run(args, t_start: float, root: Path = spec.ROOT, chips: bool = True,
                and n_tok >= 1)
 
     pk = device.peaks(dev["kind"]) if chips else device.peaks("TPU v5e")
-    r = Run(cell, arch, bitplane, setup_s, client, summary, pk)
+    r = Run(cell, arch_module, arch, bitplane, setup_s, client, summary, pk)
     metrics = spec.read_metrics(cell.per_layer if args.trace
                                 else cell.end_to_end, r, root)
     dev_out = dict(dev, memory_peak_bytes=memory)

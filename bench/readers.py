@@ -8,8 +8,6 @@ from typing import List, Optional
 
 import numpy as np
 
-from bench import cost
-
 
 def p95(values: List[float]) -> Optional[float]:
     return float(np.percentile(values, 95)) if values else None
@@ -102,7 +100,7 @@ def fused_kernel_roofline(run) -> Optional[float]:
     secs = s.kernels.get("fused_kernel") if s is not None else None
     if not secs or not run.bitplane:
         return None
-    kv = sum(cost.decode_step_need(run.arch, d, True)[2]
+    kv = sum(run.arch_module.decode_step_need(run.arch, d, True)[2]
              for d in _window_decodes(run))
     return 100.0 * kv / run.peaks["hbm_bytes_per_s"] / secs
 
@@ -117,7 +115,8 @@ def decode_mfu(run) -> Optional[float]:
         return None
     least = 0.0
     for d in steps:
-        flops, nbytes, _ = cost.decode_step_need(run.arch, d, run.bitplane)
+        flops, nbytes, _ = run.arch_module.decode_step_need(run.arch, d,
+                                                            run.bitplane)
         least += max(flops / run.peaks["bf16_flops_per_s"],
                      nbytes / run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / s.window_s

@@ -1,16 +1,20 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` names the cell, its
-configuration file and its metrics; the traffic mix is
-``bench/traffic/<traffic>.json`` and each metric's reader is
-``bench/metrics/<metric>.py``.  Adding a cell, configuration, mix or metric
-is adding files and entries, never editing code."""
+configuration file and its metrics; the configuration's public
+``model_type`` names its architecture file ``bench/archs/<model_type>.py``;
+the traffic mix is ``bench/traffic/<traffic>.json`` and each metric's
+reader is ``bench/metrics/<metric>.py``.  Adding a cell, configuration,
+architecture, mix or metric is adding files and entries, never editing
+code."""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -55,17 +59,40 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 root=root)
 
 
-def metric_reader(name: str, root: Path = ROOT) -> Callable:
-    """``read(run) -> float | None`` from ``bench/metrics/<name>.py``
-    (loaded by path: metric names may hold dots)."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load(path: Path, module_name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module_name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
+    # registered before it runs: a dataclass looks its module up by name
+    sys.modules[module_name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT) -> Callable:
+    """``read(run) -> float | None`` from ``bench/metrics/<name>.py``
+    (loaded by path: metric names may hold dots)."""
+    return _load(root / "bench" / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
+
+
+def arch_module(model_type: str, root: Path = ROOT) -> ModuleType:
+    """The architecture file ``bench/archs/<model_type>.py``; its four
+    functions are described in ``bench/archs/llama.py``."""
+    path = root / "bench" / "archs" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no architecture file {path} for "
+                                f"model_type {model_type!r}")
+    return _arch_file(path.resolve(), model_type)
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_file(path: Path, model_type: str) -> ModuleType:
+    """Each file loaded once per process: its ``parse`` result keys the
+    weights' and the reference's compiled programs, so a second load, with
+    classes of its own, would miss them."""
+    return _load(path, f"bench_arch_{model_type}")
 
 
 def read_metrics(entries: List[dict], run, root: Path = ROOT

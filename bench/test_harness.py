@@ -19,8 +19,8 @@ import jax
 import numpy as np
 import pytest
 
-from bench import harness, probe
-from bench.arch import Arch, make_weights
+from bench import harness, probe, spec
+from bench.arch import make_weights
 from bench.reference import llama
 
 BENCH = Path(__file__).resolve().parent
@@ -40,10 +40,12 @@ def _config(src: str, name: str, **serving) -> dict:
 @pytest.fixture
 def root(tmp_path):
     """A checkout holding only throwaway entries: two tiny cells, their
-    configurations and mixes, the metric readers, and two metrics more."""
+    configurations and mixes, the architecture files, the metric readers,
+    and two metrics more."""
     (tmp_path / "bench" / "configs").mkdir(parents=True)
     (tmp_path / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", tmp_path / "bench" / "metrics")
+    shutil.copytree(BENCH / "archs", tmp_path / "bench" / "archs")
     (tmp_path / "bench" / "metrics" / "requests_served.py").write_text(
         "def read(run):\n"
         "    return sum(1 for r in run.client.records.values() if r.stamps)\n")
@@ -150,15 +152,16 @@ def test_fp8_control_fails_the_limit(seed, bitplane):
     """The reference computed in fp8 in the program's place, read at each
     of 224 positions for the token it puts first."""
     cfg = _config("smollm-135m-bp", "tiny-bp")
-    arch = Arch.from_config(cfg)
+    llama_arch = spec.arch_module(cfg["model_type"])
+    arch = llama_arch.parse(cfg)
     tokens = np.random.default_rng(seed).integers(0, 256, 256,
                                                   dtype=np.int32)
     keep = None
     if bitplane:  # pages read at 8 and 12 planes from position 64 on
         keep = np.full((256, 16), 16, np.int32)
         keep[64:, :3], keep[64:, 3:6] = 8, 12
-    gaps = llama.logit_gaps(make_weights(arch, seed), arch, tokens, 32,
-                            keep_rows=keep, levels=(8, 12, 16),
+    gaps = llama.logit_gaps(make_weights(llama_arch, arch, seed), arch,
+                            tokens, 32, keep_rows=keep, levels=(8, 12, 16),
                             control=True, pad_to=256)
     assert len(gaps) == 224
     assert gaps.max() > cfg["check"]["max_logit_gap"]
@@ -215,3 +218,85 @@ def test_decode_step_that_keeps_its_cache_is_not_correct(
     assert calls[0] > 0
     assert res["correct"] is False
     assert res["checks"]["max_logit_gap"]["value"] > 0.1
+
+
+TOY_ARCH = '''\
+import dataclasses
+from pathlib import Path
+
+from bench import spec
+
+_llama = spec.arch_module("llama", Path(__file__).resolve().parents[2])
+weight_shapes = _llama.weight_shapes
+model_config = _llama.model_config
+
+
+@dataclasses.dataclass(frozen=True)
+class ToyArch(_llama.Arch):
+    marker: str = "toyllama"
+
+
+def parse(config):
+    return ToyArch(**dataclasses.asdict(_llama.parse(config)))
+
+
+def decode_step_need(arch, decoded, bitplane):
+    flops, nbytes, kv = _llama.decode_step_need(arch, decoded, bitplane)
+    return 2 * flops, nbytes, kv
+'''
+
+#: the FLOPs of the window's first decode step, as the run's architecture
+#: counts them, where that architecture is the toy
+TOY_FLOPS = '''\
+def read(run):
+    steps = [s.decoded for s in run.client.window_steps() if s.decoded]
+    if getattr(run.arch, "marker", None) != "toyllama" or not steps:
+        return None
+    return run.arch_module.decode_step_need(run.arch, steps[0], False)[0]
+'''
+
+
+def _add_cell(root, model_type: str) -> str:
+    """A tiny dense configuration of ``model_type`` and a backlog cell of
+    it, added to the checkout as files and entries; returns the cell."""
+    cfg = _config("yi-9b-s8", f"tiny-{model_type}", max_batch=2,
+                  max_ctx=256)
+    cfg["model_type"] = model_type
+    (root / "bench" / "configs" / f"tiny-{model_type}.json").write_text(
+        json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    cell = f"tiny-{model_type}.backlog"
+    bench["configs"].append({"name": f"tiny-{model_type}",
+                             "file": f"bench/configs/tiny-{model_type}.json"})
+    bench["workloads"].append({"name": cell, "config": f"tiny-{model_type}",
+                               "traffic": "tiny-backlog", "chips": 1})
+    bench["end_to_end"].append({"name": "toy_step_flops", "unit": "flop",
+                                "workloads": [cell]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return cell
+
+
+def test_architecture_added_as_a_file_alone_runs_and_passes(
+        root, own_cache_config):
+    (root / "bench" / "archs" / "toyllama.py").write_text(TOY_ARCH)
+    (root / "bench" / "metrics" / "toy_step_flops.py").write_text(TOY_FLOPS)
+    cell = _add_cell(root, "toyllama")
+    seen = {}
+    res = _run(root, cell, 5, on_window=lambda c: seen.update(client=c))
+    assert res["correct"] is True
+    llama_arch = spec.arch_module("llama")
+    cfg = json.loads(
+        (root / "bench" / "configs" / "tiny-toyllama.json").read_text())
+    first = next(s.decoded for s in seen["client"].window_steps()
+                 if s.decoded)
+    flops, _, _ = llama_arch.decode_step_need(llama_arch.parse(cfg), first,
+                                              False)
+    assert res["metrics"]["toy_step_flops"]["value"] == 2 * flops
+
+
+def test_configuration_without_an_architecture_file_fails_at_setup(
+        root, own_cache_config):
+    cell = _add_cell(root, "nosucharch")
+    with pytest.raises(FileNotFoundError) as err:
+        _run(root, cell, 0)
+    assert str(root / "bench" / "archs" / "nosucharch.py") in str(err.value)

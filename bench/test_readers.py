@@ -6,8 +6,7 @@ import types
 import numpy as np
 import pytest
 
-from bench import cost, readers
-from bench.arch import Arch
+from bench import cost, readers, spec
 from bench.client import Client, Record, Step
 
 
@@ -64,26 +63,27 @@ def test_decode_rate_counts_tokens_in_the_window():
         == pytest.approx(3 / 10)
 
 
-ARCH = Arch(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-            d_ff=128, vocab=256, rope_theta=1e4, norm_eps=1e-5,
-            tie_embeddings=True, dtype="bfloat16")
+LLAMA = spec.arch_module("llama")
+ARCH = LLAMA.Arch(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+                  head_dim=16, d_ff=128, vocab=256, rope_theta=1e4,
+                  norm_eps=1e-5, tie_embeddings=True, dtype="bfloat16")
 
 
 def test_kv_bytes_follow_the_plane_map_of_live_pages():
     per_token = ARCH.kv_bytes_per_token()
     assert per_token == 2 * 2 * 2 * 16 * 2
-    assert cost.kv_bytes(ARCH, 40, None) == 40 * per_token
+    assert cost.kv_bytes(per_token, 40, None) == 40 * per_token
     row = np.array([16, 8, 12, 4, 4])  # pages past the length do not count
     want = per_token * (16 * 16 + 16 * 8 + 8 * 12) / 16
-    assert cost.kv_bytes(ARCH, 40, row) == pytest.approx(want)
+    assert cost.kv_bytes(per_token, 40, row) == pytest.approx(want)
 
 
 def test_decode_step_need_adds_weights_once():
     dec = [(0, 40, None), (1, 20, None)]
-    flops, nbytes, kv = cost.decode_step_need(ARCH, dec, bitplane=False)
+    flops, nbytes, kv = LLAMA.decode_step_need(ARCH, dec, bitplane=False)
     assert kv == 60 * ARCH.kv_bytes_per_token()
-    assert nbytes == kv + cost.weight_bytes(ARCH)
-    one, _, _ = cost.decode_step_need(ARCH, dec[:1], bitplane=False)
+    assert nbytes == kv + LLAMA.weight_bytes(ARCH)
+    one, _, _ = LLAMA.decode_step_need(ARCH, dec[:1], bitplane=False)
     assert flops > one
 
 
@@ -93,10 +93,10 @@ def test_decode_mfu_is_least_time_over_the_window():
     c = client_with([], steps=steps)
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
     trace = types.SimpleNamespace(window_s=10.0)
-    run = types.SimpleNamespace(client=c, arch=ARCH, bitplane=False,
-                                trace=trace, peaks=peaks)
+    run = types.SimpleNamespace(client=c, arch_module=LLAMA, arch=ARCH,
+                                bitplane=False, trace=trace, peaks=peaks)
     least = sum(max(f / 1e12, b / 1e9) for f, b, _ in (
-        cost.decode_step_need(ARCH, s.decoded, False) for s in steps))
+        LLAMA.decode_step_need(ARCH, s.decoded, False) for s in steps))
     assert readers.decode_mfu(run) == pytest.approx(100 * least / 10.0)
 
 

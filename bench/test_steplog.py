@@ -18,10 +18,12 @@ METRICS = ("engine_tick_ms.backlog", "kv_pages_ms.backlog",
 
 @pytest.fixture
 def root(tmp_path):
-    """A checkout holding one tiny backlog cell and the metric readers."""
+    """A checkout holding one tiny backlog cell, the architecture files
+    and the metric readers."""
     (tmp_path / "bench" / "configs").mkdir(parents=True)
     (tmp_path / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", tmp_path / "bench" / "metrics")
+    shutil.copytree(BENCH / "archs", tmp_path / "bench" / "archs")
     cfg = _config("smollm-135m-bp", "tiny-bp", max_batch=2, max_ctx=256)
     (tmp_path / "bench" / "configs" / "tiny-bp.json").write_text(
         json.dumps(cfg))
