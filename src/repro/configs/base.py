@@ -54,6 +54,28 @@ class ModelConfig:
     moe_top_k: int = 0
     capacity_factor: float = 1.25
     expert_shard: str = "tp"  # 'ep' (experts over model axis) | 'tp' (d_ff)
+    #: router width when this chip holds a share of the experts (0 = the
+    #: router spans the ``n_experts`` held here); ``held_experts`` are the
+    #: global ids of those ``n_experts`` (empty = 0 .. n_experts - 1)
+    n_routed_experts: int = 0
+    held_experts: tuple = ()
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0
+    # --- leading dense layers before the MoE stack (first_k_dense_replace) ---
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # --- MLA (multi-head latent attention, DeepSeek-V2): kv_lora_rank > 0 ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- YaRN rope scaling (yarn_factor > 1) ---
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_heads: int = 0

@@ -34,6 +34,7 @@ Design notes (DESIGN.md §3):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,13 @@ from repro.kernels.paged_attention.ops import (
     pack_kv_planes,
 )
 from repro.kernels.paged_attention.ref import PAGE, unpack_kv_ref
-from repro.models.layers import apply_rope, he_init, rope_angles
+from repro.models.layers import (
+    apply_rope,
+    he_init,
+    rmsnorm,
+    rmsnorm_params,
+    rope_angles,
+)
 
 NEG_INF = -1e30
 
@@ -226,7 +233,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def _flash_attention_body(
     q, k, v, head_map, *, q_pos, kv_valid, window, bidirectional, chunk, kv_pos
 ):
-    """Returns (out (B,Sq,Hp,hd), lse (B,Hp,Sq) fp32)."""
+    """Returns (out (B,Sq,Hp,hd_v), lse (B,Hp,Sq) fp32)."""
     B, Sq, Hp, hd = q.shape
     Skv = k.shape[1]
     chunk = int(min(chunk, Skv))
@@ -279,7 +286,8 @@ def _flash_attention_body(
 
     m0 = jnp.full((B, Hp, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hp, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Hp, Sq, hd), jnp.float32)
+    # values may be narrower than queries and keys (MLA: 128 against 192)
+    a0 = jnp.zeros((B, Hp, Sq, v.shape[-1]), jnp.float32)
     xs = (kc, vc, jnp.arange(n_chunks))
     if kv_pos is not None:
         xs = xs + (kpc,)
@@ -708,3 +716,175 @@ def attn_apply(
         out = out * valid[None, None, :, None]
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (HF ``yarn_get_mscale``)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg) -> np.ndarray:
+    """(qk_rope_head_dim // 2,) rotary frequencies of an MLA model.  With
+    YaRN (``yarn_factor`` > 1, HF ``DeepseekV2YarnRotaryEmbedding``) the
+    dims that turn fewer than ``yarn_beta_slow`` times over the original
+    context are slowed by the factor, those that turn more than
+    ``yarn_beta_fast`` times keep their frequency, and a linear ramp joins
+    the two."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    factor = cfg.yarn_factor
+    if factor <= 1:
+        return extra.astype(np.float32)
+
+    def dim_of(rotations):
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times YaRN's ``mscale_all_dim`` factor
+    squared (HF ``DeepseekV2Attention.softmax_scale``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def mla_rope(x, pos, cfg):
+    """Rotate x (B, S, H, qk_rope_head_dim) at positions pos (B, S) as HF
+    DeepSeek-V2 does: the interleaved pairs are first regrouped, evens then
+    odds, and the two halves rotate (``rotate_half``), with cos and sin
+    scaled by ``mscale / mscale_all_dim``."""
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(yarn_inv_freq(cfg))
+    m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+         / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, jnp.cos(ang) * m, jnp.sin(ang) * m)
+
+
+def mla_params(key, cfg, dtype):
+    """q: d -> H x (nope + rope) (no q LoRA); kv_a: d -> kv_lora_rank +
+    rope, with an RMSNorm on the rank part; kv_b: rank -> H x (nope + v),
+    held as its key half ``wk_b`` and value half ``wv_b``; o: H x v -> d."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, pe, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 5)
+    return {
+        "wq": he_init(ks[0], (d, h, nope + pe), dtype, fan_in=d),
+        "wkv_a": he_init(ks[1], (d, r + pe), dtype, fan_in=d),
+        "kv_norm": rmsnorm_params(r, dtype),
+        "wk_b": he_init(ks[2], (r, h, nope), dtype, fan_in=r),
+        "wv_b": he_init(ks[3], (r, h, vd), dtype, fan_in=r),
+        "wo": he_init(ks[4], (h, vd, d), dtype, fan_in=h * vd),
+    }
+
+
+def mla_apply(params, x, cfg, *, pos, cache=None, cache_len=None):
+    """One MLA sub-layer.  x: (B, S, d); pos: (B, S).
+
+    The cache is latent: one ``c_kv`` row (kv_lora_rank, after the kv_a
+    RMSNorm) and one rotated ``k_pe`` row (qk_rope_head_dim) per token,
+    shared by every head.  ``cache`` is (c_kv (B, S_cache, r), k_pe (B,
+    S_cache, rope)), read only: rows [0, cache_len) are valid.
+
+    Returns (y (B, S, d), (c_kv, k_pe) of the S new tokens); the caller
+    writes the new rows into its cache.  A decode step (S == 1 with a
+    cache) attends in absorbed form over the latent rows; a prefill chunk
+    (scalar ``cache_len``) or a cacheless sequence decompresses them.
+    """
+    with jax.named_scope("mla_attention"):
+        nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+        q_nope = q[..., :nope]
+        q_pe = mla_rope(q[..., nope:], pos, cfg)
+        kv = jnp.einsum("bsd,dk->bsk", x, params["wkv_a"])
+        c = rmsnorm(kv[..., :r], params["kv_norm"], cfg.norm_eps)
+        k_pe = mla_rope(kv[..., None, r:], pos, cfg)[..., 0, :]
+        scale = mla_softmax_scale(cfg)
+        if cache is not None and x.shape[1] == 1:
+            o = _mla_absorbed(params, q_nope[:, 0], q_pe[:, 0], c[:, 0],
+                              k_pe[:, 0], cache, cache_len, scale)[:, None]
+        else:
+            o = _mla_decompressed(params, q_nope, q_pe, c, k_pe, cache,
+                                  cache_len, pos, scale)
+        y = jnp.einsum("bshv,hvd->bsd", o, params["wo"])
+    return y, (c, k_pe)
+
+
+def _mla_absorbed(params, q_nope, q_pe, c, k_pe, cache, cache_len, scale):
+    """One token per row against its latent rows, with W_UK folded into the
+    query and W_UV into the output, so the step reads only the latent rows:
+    score = (q_nope W_UK) . c_kv + q_pe . k_pe, out = (p . c_kv) W_UV.
+
+    q_nope (B, H, nope), q_pe (B, H, rope); c (B, r) and k_pe (B, rope) are
+    the token's own rows, which take part beside the cache's rows [0,
+    cache_len) (B,) without being written into them.  Returns (B, H, v)."""
+    ck, cp = cache
+    dt = ck.dtype
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, params["wk_b"],
+                       preferred_element_type=f32).astype(dt)
+    q_pe = q_pe.astype(dt)
+    c, k_pe = c.astype(dt), k_pe.astype(dt)
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, ck, preferred_element_type=f32)
+         + jnp.einsum("bhp,bsp->bhs", q_pe, cp, preferred_element_type=f32))
+    s_self = (jnp.einsum("bhr,br->bh", q_lat, c, preferred_element_type=f32)
+              + jnp.einsum("bhp,bp->bh", q_pe, k_pe,
+                           preferred_element_type=f32))
+    ok = jnp.arange(ck.shape[1])[None, :] < cache_len[:, None]  # (B, S)
+    s = jnp.where(ok[:, None, :], s * scale, NEG_INF)
+    s_self = s_self * scale
+    m = jnp.maximum(s.max(axis=-1), s_self)  # (B, H)
+    p = jnp.exp(s - m[..., None])
+    p_self = jnp.exp(s_self - m)
+    l = p.sum(axis=-1) + p_self
+    ctx = (jnp.einsum("bhs,bsr->bhr", p.astype(dt), ck,
+                      preferred_element_type=f32)
+           + p_self[..., None] * c.astype(f32)[:, None, :])
+    ctx = (ctx / l[..., None]).astype(dt)
+    return jnp.einsum("bhr,rhv->bhv", ctx, params["wv_b"])
+
+
+def _mla_decompressed(params, q_nope, q_pe, c, k_pe, cache, cache_len, pos,
+                      scale):
+    """Naive MLA: the new rows join the cache's (at ``cache_len``) or stand
+    alone; every row decompresses into per-head keys [c W_UK, k_pe] and
+    values c W_UV, and the queries attend causally (flash_attention).
+    Returns (B, S, H, v)."""
+    if cache is None:
+        c_all, pe_all, kv_valid = c, k_pe, pos[:, -1] + 1
+    else:
+        ck, cp = cache
+        c_all = jax.lax.dynamic_update_slice(ck, c.astype(ck.dtype),
+                                             (0, cache_len, 0))
+        pe_all = jax.lax.dynamic_update_slice(cp, k_pe.astype(cp.dtype),
+                                              (0, cache_len, 0))
+        kv_valid = cache_len + c.shape[1]
+    k_nope = jnp.einsum("bsr,rhn->bshn", c_all, params["wk_b"])
+    v = jnp.einsum("bsr,rhv->bshv", c_all, params["wv_b"])
+    pe_h = jnp.broadcast_to(pe_all[:, :, None, :].astype(k_nope.dtype),
+                            k_nope.shape[:3] + pe_all.shape[-1:])
+    k = jnp.concatenate([k_nope, pe_h], axis=-1)
+    q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)], axis=-1)
+    # flash_attention scales scores by 1/sqrt(head dim): fold the rest of
+    # the MLA scale into the queries
+    q = (q.astype(jnp.float32) * (scale * math.sqrt(q.shape[-1]))).astype(
+        q_nope.dtype)
+    hm = jnp.arange(q.shape[2], dtype=jnp.int32)
+    return flash_attention(q, k, v, hm, q_pos=pos, kv_valid=kv_valid)

@@ -16,6 +16,12 @@ all-to-all-class collectives under pjit.
 
 Shared experts (DeepSeekMoE) are plain always-on MLPs added to the routed
 output.
+
+:func:`moe_share_apply` is the expert layer of one chip of an
+expert-parallel deployment: the router keeps its published width
+(``n_routed_experts``) and top-k, and the chip computes the part of the
+result its ``held_experts`` give.  Picks of absent experts add nothing
+here; on the deployment the exchange of expert outputs brings them in.
 """
 
 from __future__ import annotations
@@ -30,7 +36,7 @@ def moe_params(key, cfg, dtype):
     d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": he_init(ks[0], (d, e), dtype),
+        "router": he_init(ks[0], (d, cfg.n_routed_experts or e), dtype),
         "w_gate": he_init(ks[1], (e, d, ff), dtype, fan_in=d),
         "w_in": he_init(ks[2], (e, d, ff), dtype, fan_in=d),
         "w_out": he_init(ks[3], (e, ff, d), dtype, fan_in=ff),
@@ -115,3 +121,40 @@ def moe_apply(params, x, cfg, inference: bool = False):
     if cfg.n_shared_experts:
         out = out + mlp_apply(params["shared"], x, "swiglu")
     return out, aux.mean()
+
+
+def moe_share_apply(params, x, cfg):
+    """The expert layer at this chip's share.  x: (B, S, d) ->
+    ((B, S, d), (B,) int32 count of each row's (token, pick) pairs routed
+    to a held expert).
+
+    The router scores all ``n_routed_experts`` (softmax in float32, then
+    greedy top-k); gates are renormalised over the k picks only where
+    ``norm_topk_prob`` holds, then scaled by ``routed_scaling_factor``.
+    Every held expert runs on every token and its output is weighted by
+    the token's gate for it, which is zero where the token did not pick
+    it: dropless, and at decode bound by the held experts' weight bytes
+    either way.  The shared experts run on every token."""
+    k = cfg.moe_top_k
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("bsd,de->bse", x, params["router"],
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, k)  # (B, S, k)
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        gate = gate * cfg.routed_scaling_factor
+        held = jnp.asarray(cfg.held_experts or range(cfg.n_experts),
+                           jnp.int32)
+        hit = idx[..., None] == held  # (B, S, k, e)
+        w = jnp.sum(jnp.where(hit, gate[..., None], 0.0), axis=2)  # (B,S,e)
+        picks = hit.sum(axis=(1, 2, 3), dtype=jnp.int32)
+    with jax.named_scope("moe_experts"):
+        g = jnp.einsum("bsd,edf->bsef", x, params["w_gate"])
+        u = jnp.einsum("bsd,edf->bsef", x, params["w_in"])
+        h = jax.nn.silu(g) * u * w[..., None].astype(x.dtype)
+        out = jnp.einsum("bsef,efd->bsd", h, params["w_out"])
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + mlp_apply(params["shared"], x, "swiglu")
+    return out, picks

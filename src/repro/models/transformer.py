@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.attention import attn_apply, attn_params
+from repro.models.attention import attn_apply, attn_params, mla_apply, mla_params
 from repro.models.layers import (
     embed_apply,
     embed_params,
@@ -26,7 +26,7 @@ from repro.models.layers import (
     rmsnorm,
     rmsnorm_params,
 )
-from repro.models.moe import moe_apply, moe_params
+from repro.models.moe import moe_apply, moe_params, moe_share_apply
 from repro.models.frontends import VISION_DIM
 
 
@@ -42,6 +42,8 @@ def _stack_layer_params(cfg, key, init_one):
 
 
 def init_lm_params(cfg, key):
+    if cfg.kv_lora_rank:
+        return _init_mla_lm_params(cfg, key)
     dtype = pdtype(cfg)
     k_embed, k_layers, k_head, k_patch = jax.random.split(key, 4)
 
@@ -67,6 +69,38 @@ def init_lm_params(cfg, key):
         params["lm_head"] = lm_head_params(k_head, cfg.vocab_padded, cfg.d_model, dtype)
     if cfg.family == "vlm":
         params["patch_proj"] = he_init(k_patch, (VISION_DIM, cfg.d_model), dtype)
+    return params
+
+
+def _init_mla_lm_params(cfg, key):
+    """Latent-attention stack (DeepSeek-V2): MLA and its norms in every
+    layer (``layers``), then a silu-gated MLP in each of the first
+    ``n_dense_layers`` (``dense``) and the expert layer at this chip's
+    share in the rest (``moe``)."""
+    dtype = pdtype(cfg)
+    d, k = cfg.d_model, cfg.n_dense_layers
+    k_embed, k_layers, k_dense, k_moe, k_head = jax.random.split(key, 5)
+
+    def stack(key, n, init_one):
+        return jax.vmap(init_one)(jax.random.split(key, n))
+
+    params = {
+        "embed": embed_params(k_embed, cfg.vocab_padded, d, dtype),
+        "layers": stack(k_layers, cfg.n_layers, lambda kk: {
+            "ln1": rmsnorm_params(d, dtype),
+            "attn": mla_params(kk, cfg, dtype),
+            "ln2": rmsnorm_params(d, dtype),
+        }),
+        "final_norm": rmsnorm_params(d, dtype),
+    }
+    if k:
+        params["dense"] = {"mlp": stack(k_dense, k, lambda kk: mlp_params(
+            kk, d, cfg.dense_d_ff, "swiglu", dtype))}
+    if cfg.n_layers > k:
+        params["moe"] = stack(k_moe, cfg.n_layers - k,
+                              lambda kk: moe_params(kk, cfg, dtype))
+    if not cfg.tie_embeddings:
+        params["lm_head"] = lm_head_params(k_head, cfg.vocab_padded, d, dtype)
     return params
 
 
@@ -133,6 +167,61 @@ def _layer_seq(lp, x, cfg, pos, cache_kv, cache_len, want_cache,
     return x, new_kv, aux
 
 
+def _mla_stack(params, cfg, x, pos, cache=None, cache_len=None, slot=None):
+    """One scan over every layer of a latent-attention model: MLA, then the
+    layer's dense MLP (the first ``n_dense_layers``) or its expert layer,
+    picked by ``lax.cond`` on the layer index.  Every layer's cache rows
+    are scanned in place, so the stacked cache is never sliced or joined
+    around the dense layers.
+
+    cache: (c_kv, k_pe) stacked (L, B, S_cache, .), read only; with
+    ``slot`` each layer attends over that one batch row's rows.  Returns
+    (x, (c_kv, k_pe) of x's tokens stacked (L, B_x, S_x, .), (B_x,) int32
+    (token, pick) pairs routed to held experts, summed over the layers)."""
+    k = cfg.n_dense_layers
+    n_moe = cfg.n_layers - k
+    no_picks = jnp.zeros(x.shape[0], jnp.int32)
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            tree)
+
+    def dense(i, h):
+        mlp = at(params["dense"]["mlp"], jnp.minimum(i, k - 1))
+        return mlp_apply(mlp, h, "swiglu"), no_picks
+
+    def moe(i, h):
+        return moe_share_apply(at(params["moe"], jnp.maximum(i - k, 0)), h,
+                               cfg)
+
+    def ffn(i, h):
+        if not k:
+            return moe(i, h)
+        if not n_moe:
+            return dense(i, h)
+        return jax.lax.cond(i < k, dense, moe, i, h)
+
+    def body(carry, xs):
+        x, picks = carry
+        lp, i, *kv = xs
+        if kv and slot is not None:
+            kv = [jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
+                  for a in kv]
+        h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        a, rows = mla_apply(lp["attn"], h, cfg, pos=pos,
+                            cache=tuple(kv) or None, cache_len=cache_len)
+        x = x + a
+        y, p = ffn(i, rmsnorm(x, lp["ln2"], cfg.norm_eps))
+        return (x + y, picks + p), rows
+
+    xs = (params["layers"], jnp.arange(cfg.n_layers))
+    if cache is not None:
+        xs = xs + tuple(cache)
+    (x, picks), rows = jax.lax.scan(body, (x, no_picks), xs)
+    return x, rows, picks
+
+
 def run_stack(params, cfg, x, pos, cache=None, want_cache=False, remat=None,
               keeps=None, decode_kernel="fused"):
     """x: (B, S, d). cache: {'k','v'} stacked (L, B, Smax, Hkv, hd) + 'len'
@@ -149,8 +238,16 @@ def run_stack(params, cfg, x, pos, cache=None, want_cache=False, remat=None,
     serving ladder ranks on the last layer and applies everywhere, so it is
     closed over, not scanned); ``keeps`` is that map's static value set.
 
+    Latent-attention models (``cfg.kv_lora_rank``) run a cacheless
+    sequence here (:func:`_mla_stack`); their decode and prefill chunks go
+    through :func:`lm_decode` and :func:`lm_prefill_chunk`.
+
     Returns (x_final, new_cache_stack_or_None, aux_sum).
     """
+    if cfg.kv_lora_rank:
+        assert cache is None, "latent caches decode through lm_decode"
+        x, (c, pe), _ = _mla_stack(params, cfg, x, pos)
+        return x, ({"k": c, "v": pe} if want_cache else None), jnp.float32(0)
     remat = cfg.remat if remat is None else remat
     append_valid = None
     if cache is not None and "valid" in cache:
@@ -335,6 +432,9 @@ def lm_prefill_chunk(params, cfg, tokens, cache, slot, start, last_idx):
     ragged tail's pad can never clobber older in-window entries.  The
     serving scheduler caps bucket sizes at the window for this path.
     """
+    if cfg.kv_lora_rank:
+        return _mla_prefill_chunk(params, cfg, tokens, cache, slot, start,
+                                  last_idx)
     ring = "pos" in cache
     bitplane = "k_planes" in cache
     # bit-plane caches stack as (L, bits, B, S, ...): the slot axis moves
@@ -368,6 +468,56 @@ def lm_prefill_chunk(params, cfg, tokens, cache, slot, start, last_idx):
     return logits.astype(jnp.float32), out
 
 
+def _mla_prefill_chunk(params, cfg, tokens, cache, slot, start, last_idx):
+    """:func:`lm_prefill_chunk` on a latent cache: the chunk attends over
+    the slot's rows and its new rows land at [start, start + C)."""
+    x = embed_apply(params["embed"], tokens)
+    c = x.shape[1]
+    start = jnp.asarray(start, jnp.int32)
+    pos = start + jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (1, c))
+    x, rows, _ = _mla_stack(params, cfg, x, pos, (cache["k"], cache["v"]),
+                            start, slot=slot)
+    x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
+    x_last = rmsnorm(x_last, params["final_norm"], cfg.norm_eps)
+    logits = jnp.einsum("bsd,vd->bsv", x_last, head_weight(params))[:, 0]
+    out = dict(cache)
+    for name, new in zip(("k", "v"), rows):  # new: (L, 1, C, .)
+        out[name] = jax.lax.dynamic_update_slice(
+            cache[name], new.astype(cache[name].dtype), (0, slot, start, 0))
+    return logits.astype(jnp.float32), out
+
+
+def _mla_decode(params, cfg, token, cache):
+    """:func:`lm_decode` on a latent cache.  The layers read the cache
+    without writing it; after the last layer each row's new latent rows of
+    every layer are written at its own position, one dynamic-update-slice
+    per row and stream (a scatter of all rows at once makes the TPU
+    compiler relayout the whole cache twice).  ``moe_held_picks`` in the
+    returned cache holds each row's (token, pick) pairs routed to held
+    experts this step."""
+    x = embed_apply(params["embed"], token[:, None])
+    b = x.shape[0]
+    ln = jnp.asarray(cache["len"], jnp.int32)
+    if ln.ndim == 0:
+        ln = jnp.broadcast_to(ln, (b,))
+    x, rows, picks = _mla_stack(params, cfg, x, ln[:, None],
+                                (cache["k"], cache["v"]), ln)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    logits = jnp.einsum("bsd,vd->bsv", x, head_weight(params))[:, 0]
+    at = jnp.clip(ln, 0, cache["k"].shape[2] - 1)
+    new_cache = dict(cache, len=cache["len"] + 1)
+    for name, new in zip(("k", "v"), rows):  # new: (L, B, 1, .)
+        c = cache[name]
+        new = new.astype(c.dtype)
+        for i in range(b):
+            c = jax.lax.dynamic_update_slice(c, new[:, i:i + 1],
+                                             (0, i, at[i], 0))
+        new_cache[name] = c
+    if "moe_held_picks" in cache:
+        new_cache["moe_held_picks"] = picks
+    return logits.astype(jnp.float32), new_cache
+
+
 def lm_decode(params, cfg, token, cache, keeps=None, decode_kernel="fused"):
     """token: (B,) int32; cache from prefill or init_decode_cache.
 
@@ -386,8 +536,12 @@ def lm_decode(params, cfg, token, cache, keeps=None, decode_kernel="fused"):
     A staged cache with a per-row 'sbase' (continuous batching) advances
     each row's staging base here when its ring filled and was folded back.
 
+    A latent cache (``cfg.kv_lora_rank``) takes :func:`_mla_decode`.
+
     Returns (logits (B, Vpad), new cache).
     """
+    if cfg.kv_lora_rank:
+        return _mla_decode(params, cfg, token, cache)
     x = embed_apply(params["embed"], token[:, None])
     ln = jnp.asarray(cache["len"], jnp.int32)
     if ln.ndim == 1:
@@ -407,11 +561,32 @@ def lm_decode(params, cfg, token, cache, keeps=None, decode_kernel="fused"):
     return logits.astype(jnp.float32), new_cache
 
 
+def kv_row_shapes(cfg) -> dict:
+    """One token's row in each cache stream of one layer: GQA's K and V
+    are (n_kv_heads, head_dim); a latent (MLA) cache's "k" stream carries
+    ``c_kv`` (kv_lora_rank,) and its "v" stream the rotated ``k_pe``
+    (qk_rope_head_dim,)."""
+    if cfg.kv_lora_rank:
+        return {"k": (cfg.kv_lora_rank,), "v": (cfg.qk_rope_head_dim,)}
+    row = (cfg.n_kv_heads, cfg.head_dim)
+    return {"k": row, "v": row}
+
+
 def init_decode_cache(cfg, batch, max_len, dtype=None):
     """Sliding-window archs get a ring buffer of the window size (the cache
     for a ``max_len`` context is bounded by the window — Mixtral's SWA is
-    exactly why its ``long_500k`` cell is feasible)."""
+    exactly why its ``long_500k`` cell is feasible).  A latent-attention
+    model's cache holds its latent rows (:func:`kv_row_shapes`), plus
+    ``moe_held_picks`` (B,) int32 that each decode step returns where the
+    model has expert layers."""
     dtype = dtype or pdtype(cfg)
+    if cfg.kv_lora_rank:
+        cache = {name: jnp.zeros((cfg.n_layers, batch, max_len) + row, dtype)
+                 for name, row in kv_row_shapes(cfg).items()}
+        cache["len"] = jnp.int32(0)
+        if cfg.n_layers > cfg.n_dense_layers:
+            cache["moe_held_picks"] = jnp.zeros(batch, jnp.int32)
+        return cache
     ring = 0 < cfg.attn_window < max_len
     s_cache = cfg.attn_window if ring else max_len
     shape = (cfg.n_layers, batch, s_cache, cfg.n_kv_heads, cfg.head_dim)
@@ -484,7 +659,8 @@ def ring_cache_from_prefill(cache, cfg, max_len):
     if not (0 < w < max_len):
         out = dict(cache)
         if s < max_len:
-            pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0))
+            pad = ((0, 0), (0, 0), (0, max_len - s)) + ((0, 0),) * (
+                cache["k"].ndim - 3)
             out["k"] = jnp.pad(cache["k"], pad)
             out["v"] = jnp.pad(cache["v"], pad)
         if cfg.decode_staging > 0 and "sk" not in out:

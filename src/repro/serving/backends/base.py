@@ -67,6 +67,7 @@ from repro.core.quantization import (
 )
 from repro.memctl import CompressionEngineRuntime, Job, JobClass
 from repro.memctl.runtime import aggregate_engine_reports
+from repro.models.transformer import kv_row_shapes
 from repro.serving.kv_cache import (
     PAGE_TOKENS,
     CompressedKVStore,
@@ -89,6 +90,10 @@ BACKEND_STATS = (
     "prefix_requests_matched", "prefix_tokens_matched",
     "prefix_pages_matched", "prefix_bytes_deduped",
 )
+
+#: per-row counters a decode step returns in its cache (see
+#: ``models.transformer.init_decode_cache``)
+DECODE_COUNTERS = ("moe_held_picks",)
 
 
 @dataclasses.dataclass
@@ -246,20 +251,23 @@ def make_fetch_job(store: CompressedKVStore, stats: Dict[str, float],
                seq_id=seq_key, size_fn=size)
 
 
-@functools.partial(jax.jit, static_argnames=("layers", "kv_shape"))
-def fill_pages(k, v, slots, rows, *, layers: int, kv_shape):
+@functools.partial(jax.jit, static_argnames=("layers", "kv_shapes"))
+def fill_pages(k, v, slots, rows, *, layers: int, kv_shapes):
     """The decode pages a step filled, every slot's at once: K and V of
     the first ``layers`` layers at device ``rows`` (n, 16) of ``slots``
-    (n,), as one (2, n, layers, 16, Hkv*hd) array for one device->host
-    transfer.  The layout of ``k``/``v`` picks the body: dense caches
-    (L, B, S, Hkv, hd) are gathered; bit-plane caches (L, bits, B, S/16,
+    (n,), as one (2, n, layers, 16, channels) array for one device->host
+    transfer (a latent cache's two streams are gathered as one width: the
+    narrower is zero-padded, and :meth:`KVBackend._copy_fill_pages` cuts
+    it back).  The layout of ``k``/``v`` picks the body: dense caches
+    (L, B, S, *row) are gathered; bit-plane caches (L, bits, B, S/16,
     1, W) gather each row's page (``rows[:, 0] // 16``: a filled page is
-    page-aligned) and unpack it at full precision, a bf16 bitcast, so the
-    result is bitwise what ``slot_kv_host`` copies.  One program per
-    (layout, layers, n, cache shape)."""
+    page-aligned) and unpack it at full precision to the stream's row
+    shape in ``kv_shapes``, a bf16 bitcast, so the result is bitwise what
+    ``slot_kv_host`` copies.  One program per (layout, layers, n, cache
+    shape)."""
     n = slots.shape[0]
 
-    def gather(c):
+    def gather(c, kv_shape):
         if c.ndim == 6:
             from repro.kernels.paged_attention.ref import unpack_kv_ref
 
@@ -272,7 +280,12 @@ def fill_pages(k, v, slots, rows, *, layers: int, kv_shape):
             dense = c[:layers][:, slots[:, None], rows]
         return jnp.moveaxis(dense.reshape(layers, n, PAGE_TOKENS, -1), 1, 0)
 
-    return jnp.stack([gather(k), gather(v)])
+    out = [gather(c, shape) for c, shape in zip((k, v), kv_shapes)]
+    width = max(o.shape[-1] for o in out)
+    if any(o.shape[-1] != width for o in out):
+        out = [jnp.pad(o, ((0, 0),) * 3 + ((0, width - o.shape[-1]),))
+               for o in out]
+    return jnp.stack(out)
 
 
 class KVBackend(abc.ABC):
@@ -288,6 +301,8 @@ class KVBackend(abc.ABC):
         self.model = model
         self.mcfg = model.cfg
         self.cfg = cfg
+        #: one token's row in each cache stream, as the model lays it out
+        self._rows = kv_row_shapes(model.cfg)
         self.device_kv = cfg.device_kv
         self.check_model(model.cfg, cfg)
         self.stats = stats if stats is not None else {}
@@ -329,6 +344,10 @@ class KVBackend(abc.ABC):
             raise NotImplementedError(
                 "sliding-window ring caches need backend='ring'"
             )
+        if mcfg.kv_lora_rank and (mcfg.attn_window or mcfg.decode_staging):
+            raise NotImplementedError(
+                "a latent (MLA) cache has no sliding-window or staged form"
+            )
         if mcfg.decode_staging > 0 and cfg.device_kv != "dense":
             raise ValueError(
                 f"decode_staging={mcfg.decode_staging} with "
@@ -344,6 +363,13 @@ class KVBackend(abc.ABC):
             raise ValueError(
                 f"device_kv must be 'dense' or 'bitplane', got "
                 f"{cfg.device_kv!r}"
+            )
+        if cfg.device_kv == "bitplane" and mcfg.kv_lora_rank:
+            raise ValueError(
+                "device_kv='bitplane' cannot hold a latent (MLA) cache: the "
+                "bit-plane row and the fused kernel are laid out per KV "
+                "head, and an MLA token has one shared c_kv and k_pe row; "
+                "serve it with device_kv='dense'"
             )
         if cfg.device_kv == "bitplane" and cfg.max_ctx % PAGE_TOKENS:
             raise ValueError(
@@ -447,6 +473,14 @@ class KVBackend(abc.ABC):
             keeps |= {planes for _, planes in self.cfg.ladder.rungs}
         return tuple(sorted(keeps))
 
+    def decode_counters(self) -> dict:
+        """Counters the last decode step returned with its cache, as device
+        arrays with one value per batch row: ``moe_held_picks`` where the
+        model has expert layers at a chip's share, else nothing.  The
+        scheduler reads them in the same wait as the step's tokens."""
+        return {name: self._cache[name] for name in DECODE_COUNTERS
+                if name in self._cache}
+
     def sync_lens(self, lens, stage_anchor=None) -> None:
         lens = jnp.asarray(lens)
         self._cache["len"] = lens
@@ -486,8 +520,9 @@ class KVBackend(abc.ABC):
         """Cache sequence-axis index holding absolute tokens [t0, t1)."""
         return slice(t0, t1)
 
-    def _kv_shape(self) -> Tuple[int, int]:
-        return self.mcfg.n_kv_heads, self.mcfg.head_dim
+    def _kv_shape(self, stream: str) -> Tuple[int, ...]:
+        """One token's row of ``stream`` ("k" or "v") in one layer."""
+        return self._rows[stream]
 
     def _write_device_rows(self, name: str, slot_id: int, rows,
                            dense) -> None:
@@ -531,10 +566,11 @@ class KVBackend(abc.ABC):
             from repro.kernels.paged_attention.ops import read_kv_rows
 
             out = []
-            for name in ("k_planes", "v_planes"):
+            for stream in ("k", "v"):
                 # (ls, bits, S/16, 1, W) -> unpack layers as the batch axis
-                pl = jnp.moveaxis(self._cache[name][:ls, :, slot_id], 1, 0)
-                dense = read_kv_rows(pl, rows, self._kv_shape())
+                pl = jnp.moveaxis(
+                    self._cache[stream + "_planes"][:ls, :, slot_id], 1, 0)
+                dense = read_kv_rows(pl, rows, self._kv_shape(stream))
                 out.append(np.asarray(dense.reshape(ls, t, -1)))
             steps.count("d2h_copies", 2)
             return tuple(out)
@@ -700,19 +736,19 @@ class KVBackend(abc.ABC):
         cache = self.ensure_cache()
         t = m - lo
         o = lo - entry.r0_token
-        hkv, hd = self.mcfg.n_kv_heads, self.mcfg.head_dim
         n_layers = entry.k.shape[0]
         rows = self._device_rows(lo, m)
         if self.device_kv == "bitplane":
-            for name, arr in (("k_planes", entry.k), ("v_planes", entry.v)):
+            for name, arr in (("k", entry.k), ("v", entry.v)):
                 dense = jnp.asarray(arr[:, o:o + t]).reshape(
-                    n_layers, t, hkv, hd
+                    n_layers, t, *self._kv_shape(name)
                 )
-                self._write_device_rows(name, slot_id, rows, dense)
+                self._write_device_rows(name + "_planes", slot_id, rows,
+                                        dense)
             return
         for name, arr in (("k", entry.k), ("v", entry.v)):
             dense = jnp.asarray(arr[:, o:o + t]).reshape(
-                n_layers, t, hkv, hd
+                n_layers, t, *self._kv_shape(name)
             ).astype(cache[name].dtype)
             cache[name] = cache[name].at[:, slot_id, rows].set(dense)
 
@@ -818,7 +854,9 @@ class KVBackend(abc.ABC):
         steps.count("d2h_bytes", dev.nbytes)
         steps.count("d2h_copies", 1)
         steps.count("fill_pages", len(fills))
-        return {s: (out[0, j], out[1, j]) for j, (s, _) in enumerate(fills)}
+        kw, vw = (int(np.prod(self._kv_shape(s))) for s in ("k", "v"))
+        return {s: (out[0, j, ..., :kw], out[1, j, ..., :vw])
+                for j, (s, _) in enumerate(fills)}
 
     def _run_fill_pages(self, fills: Sequence[Tuple[int, int]]):
         """Dispatch :func:`fill_pages` for ``fills`` padded to ``max_batch``
@@ -836,7 +874,8 @@ class KVBackend(abc.ABC):
                  else ("k", "v"))
         return fill_pages(*(self._cache[n] for n in names), slots, rows,
                           layers=self.stored_layers(),
-                          kv_shape=self._kv_shape())
+                          kv_shapes=(self._kv_shape("k"),
+                                     self._kv_shape("v")))
 
     def _commit_decode_token(self, slot_id: int, ln: int,
                              page: Optional[tuple]) -> None:
@@ -1009,7 +1048,7 @@ class KVBackend(abc.ABC):
         from repro.kernels.paged_attention.ops import read_kv_rows
 
         pl = self._cache["k_planes"][-1][:, slot_id]  # (bits, S/16, 1, W)
-        return read_kv_rows(pl, rows, self._kv_shape())
+        return read_kv_rows(pl, rows, self._kv_shape("k"))
 
     def _device_page(self, page_idx: int) -> int:
         """Device plane-map column holding this absolute page (ring layouts

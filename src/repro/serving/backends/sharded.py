@@ -41,6 +41,12 @@ class ShardedBackend(KVBackend):
     # ------------------------------------------------------------ validation
     @classmethod
     def check_model(cls, mcfg, cfg) -> None:
+        if mcfg.kv_lora_rank:
+            raise NotImplementedError(
+                "backend='sharded' routes pages by KV head or by sequence "
+                "block of a (L, B, S, Hkv, hd) cache; a latent (MLA) cache "
+                "has no head axis to split — use backend='paged'"
+            )
         if mcfg.decode_staging > 0:
             raise ValueError(
                 f"decode_staging={mcfg.decode_staging} with "

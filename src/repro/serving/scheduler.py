@@ -735,8 +735,10 @@ class ContinuousScheduler:
     # ----------------------------------------------------------------- decode
     def _decode_dispatch(self):
         """Dispatch one batched decode step + sampling; returns the pending
-        device result WITHOUT materializing it, so host-side work (the
-        prefill storage flush) overlaps the device execution."""
+        device result — the sampled tokens and the step's per-row counters
+        (``backend.decode_counters``) — WITHOUT materializing it, so
+        host-side work (the prefill storage flush) overlaps the device
+        execution."""
         b = self.cfg.max_batch
         tok = np.zeros(b, np.int32)
         draws = np.zeros(b, np.int64)
@@ -765,18 +767,22 @@ class ContinuousScheduler:
             self.params, jnp.asarray(tok), self.backend.cache
         )
         self.backend.cache = cache
-        return sample_slots(jnp.stack(keys), draws, logits, self.cfg.sampler)
+        return (sample_slots(jnp.stack(keys), draws, logits, self.cfg.sampler),
+                self.backend.decode_counters())
 
-    def _decode_commit(self, nxt_dev) -> None:
-        """Materialize the dispatched decode step (``serve.decode_wait``)
-        and run its bookkeeping (``serve.decode_commit``: outputs, lengths,
-        per-slot page traffic)."""
+    def _decode_commit(self, pending) -> None:
+        """Materialize the dispatched decode step (``serve.decode_wait``:
+        tokens and counters in one wait) and run its bookkeeping
+        (``serve.decode_commit``: outputs, lengths, per-slot page
+        traffic)."""
         with steps.span("decode_wait"):
-            nxt = np.asarray(jax.block_until_ready(nxt_dev))
+            nxt, counters = jax.device_get(pending)
         with steps.span("decode_commit"):
-            self._commit_tokens(nxt)
+            self._commit_tokens(nxt, counters)
 
-    def _commit_tokens(self, nxt: np.ndarray) -> None:
+    def _commit_tokens(self, nxt: np.ndarray, counters=None) -> None:
+        """Commit each decoding slot's token; each per-row counter of the
+        step (``counters``) adds its decoding rows to the step log."""
         b = self.cfg.max_batch
         n_dec = self.decoding
         self.stats["decode_steps"] += 1
@@ -797,6 +803,8 @@ class ContinuousScheduler:
             tokens.append((i, int(self._lens[i])))
         self.backend.on_decode_tokens(tokens)
         steps.count("decoded", n_dec)
+        for name, per_row in (counters or {}).items():
+            steps.count(name, int(sum(per_row[i] for i, _ in tokens)))
         if live and committed:
             # one shared stamp for the whole batch — the tokens
             # materialized together in one device step

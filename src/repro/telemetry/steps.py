@@ -34,7 +34,11 @@ Counters (zero unless the step did the work):
   slot whose page filled;
 * ``compiles`` / ``compile_s``: XLA compile requests during the step and
   their seconds, from JAX's ``backend_compile_duration`` event (a load
-  from the persistent compile cache is a request too).
+  from the persistent compile cache is a request too);
+* ``moe_held_picks``: (token, pick) pairs of the step's decoding rows that
+  the router sent to an expert this chip holds, summed over the expert
+  layers (models with experts at a chip's share; the decode step returns
+  it beside its tokens).
 
 ``span``, ``count`` and ``step`` are module functions, not collector
 methods: they are not gated on ``telemetry.enabled``.
@@ -53,7 +57,8 @@ import jax
 PHASES = ("admit", "prefill_dispatch", "decode_dispatch", "kv_flush",
           "decode_wait", "decode_commit", "engine_tick", "retire")
 COUNTERS = ("decoded", "memctl_jobs", "memctl_queue", "d2h_bytes",
-            "d2h_copies", "fill_pages", "compiles", "compile_s")
+            "d2h_copies", "fill_pages", "compiles", "compile_s",
+            "moe_held_picks")
 PREFIX = "serve."
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

@@ -137,7 +137,68 @@ def test_fill_page_copy_compiles_for_v5e(model, one_chip, bitplane):
                          jax.ShapeDtypeStruct((B, PAGE), jnp.int32)))
     compiled = fill_pages.lower(
         cache[names[0]], cache[names[1]], *idx, layers=4,
-        kv_shape=(cfg.n_kv_heads, cfg.head_dim)).compile()
+        kv_shapes=((cfg.n_kv_heads, cfg.head_dim),) * 2).compile()
     out = compiled.out_info
     assert out.shape == (2, B, 4, PAGE, cfg.n_kv_heads * cfg.head_dim)
     assert out.dtype == jnp.bfloat16
+
+
+def _latent_model(n_layers: int):
+    """DeepSeek-V2-Lite at its published widths (MLA rank 512, rope 64, 16
+    heads; 8 of 64 experts held, 2 shared; the dense layer first), cut to
+    ``n_layers`` layers, from the benchmark's configuration file."""
+    import json
+    from pathlib import Path
+
+    from bench import spec
+
+    arch_file = spec.arch_module("deepseek_v2")
+    cfg = json.loads((Path(spec.ROOT) / "bench" / "configs"
+                      / "deepseek-v2-lite-e8.json").read_text())
+    cfg["num_hidden_layers"] = n_layers
+    return build_model(arch_file.model_config(cfg, arch_file.parse(cfg)))
+
+
+def test_latent_decode_step_compiles_for_v5e(one_chip):
+    """The MLA + expert-share decode step, the dense layer and two expert
+    layers, at B 16 over a 4096-token latent cache.  The step reads the
+    cache in place and writes each row's new rows with a
+    dynamic-update-slice: the only cache-sized copy of each stream is the
+    output buffer an undonated step must write (a scatter of all rows made
+    the compiler relayout the whole cache twice more)."""
+    model = _latent_model(3)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def cache_fn():
+        cache = model.init_cache(16, 4096)
+        cache["len"] = jnp.zeros(16, jnp.int32)
+        return cache
+
+    args = _on(one_chip, (params, jax.ShapeDtypeStruct((16,), jnp.int32),
+                          jax.eval_shape(cache_fn)))
+    compiled = jax.jit(model.decode).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 16e9
+    lines = compiled.as_text().splitlines()
+    for shape in ("bf16[3,16,4096,512]", "bf16[3,16,4096,64]"):
+        copies = [ln for ln in lines if f"= {shape}" in ln
+                  and (" copy(" in ln or " copy-done(" in ln
+                       or " scatter(" in ln)]
+        assert len(copies) == 1, copies
+
+
+def test_latent_fill_page_copy_compiles_for_v5e(one_chip):
+    """The decode page-fill copy on latent rows: c_kv (512) and k_pe (64)
+    gathered as one width, 4 stored layers of every slot."""
+    from repro.models.transformer import kv_row_shapes
+    from repro.serving.backends.base import fill_pages
+
+    model = _latent_model(4)
+    cache = jax.eval_shape(lambda: model.init_cache(16, 4096))
+    rows = kv_row_shapes(model.cfg)
+    idx = _on(one_chip, (jax.ShapeDtypeStruct((16,), jnp.int32),
+                         jax.ShapeDtypeStruct((16, PAGE), jnp.int32)))
+    compiled = fill_pages.lower(
+        *_on(one_chip, (cache["k"], cache["v"])), *idx, layers=4,
+        kv_shapes=(rows["k"], rows["v"])).compile()
+    assert compiled.out_info.shape == (2, 16, 4, PAGE, 512)
