@@ -75,6 +75,7 @@ from repro.serving.kv_cache import (
     PageKey,
     PrefixEntry,
     PrefixIndex,
+    is_prefix_seq,
     iter_page_chunks,
     page_chain_hashes,
     prefix_seq_id,
@@ -94,6 +95,9 @@ BACKEND_STATS = (
 #: per-row counters a decode step returns in its cache (see
 #: ``models.transformer.init_decode_cache``)
 DECODE_COUNTERS = ("moe_held_picks",)
+
+#: a page's two cache streams, in the order fetch accounting visits them
+STREAMS = ("k", "v")
 
 
 @dataclasses.dataclass
@@ -134,6 +138,16 @@ class SlotState:
     #: ring window slides past them (advancing bound_from_page)
     shared_pages: int = 0
     bound_from_page: int = 0
+    # --- fetch accounting's index of the live pages' keys, one entry per
+    # (layer, stream index, page, tier index) over ``fetch_range`` ---
+    #: keys probed one by one each step: resident, evicted or dead
+    fetch_open: set = dataclasses.field(default_factory=set)
+    #: keys known to wait on a queued write or re-activation and not
+    #: resident: counted in bulk as deferrals until a put or a
+    #: cancellation reopens them
+    fetch_deferred: set = dataclasses.field(default_factory=set)
+    #: [first, last) page the two sets cover
+    fetch_range: Tuple[int, int] = (0, 0)
 
 
 class MemTier:
@@ -626,6 +640,12 @@ class KVBackend(abc.ABC):
             tier.store.drop_sequence(rid)
         self.stats["engine_jobs_cancelled"] += cancelled
         self._slots.pop(slot_id, None)
+        if st is not None and st.prefix_hashes:
+            # the cancelled jobs may have been the last queued write of a
+            # shared page another slot counts as deferred: probe those again
+            for other in self._slots.values():
+                self._reopen(other, [pair for pair in other.fetch_deferred
+                                     if pair[2] < other.prompt_pages])
         self._reset_device_planes(slot_id)
         return cancelled
 
@@ -947,10 +967,11 @@ class KVBackend(abc.ABC):
         for tier, cols in self._page_targets(key):
             part = chunk if cols is None else chunk[:, cols]
 
-            def fn(store=tier.store, key=key, part=part, st=st, valid=valid):
-                store.put_page(key, part,
-                               planes=st.page_planes.get(key.page_idx),
-                               valid_tokens=valid)
+            def fn(tier=tier, key=key, part=part, st=st, valid=valid):
+                tier.store.put_page(key, part,
+                                    planes=st.page_planes.get(key.page_idx),
+                                    valid_tokens=valid)
+                self._note_put(tier, key, st)
 
             tier.engine.submit(Job(JobClass.KV_WRITE, part[:valid].nbytes,
                                    fn=fn, key=key.astuple(),
@@ -964,44 +985,117 @@ class KVBackend(abc.ABC):
         charged once when the engine services it — possibly steps later
         under load); pages whose write or re-activation is still queued are
         skipped, since their ground truth is still the device working set
-        and no compressed-tier copy exists to fetch."""
+        and no compressed-tier copy exists to fetch.
+
+        The skipped keys are not probed: the slot's index
+        (:meth:`_sync_fetch_range`) holds them in ``fetch_deferred`` until
+        a put or a cancellation reopens them, and they count as deferrals
+        in one addition.  Only the keys in ``fetch_open`` are classified
+        one by one, in the order layer, stream, page, tier; so a step costs
+        the resident keys plus the state changes, not every live key, and
+        queues the same jobs in the same order.  The step log counts the
+        two kinds in ``fetch_probed`` and ``fetch_deferred``."""
         st = self._slots[slot_id]
         rid = st.rid
-        p0, n_pages = self._live_page_range(st)
-        for li in range(self.stored_layers()):
-            for stream in ("k", "v"):
-                for p in range(p0, n_pages):
+        self._sync_fetch_range(st)
+        bulk = len(st.fetch_deferred)
+        self.stats["kv_fetch_deferrals"] += bulk
+        probed = 0
+        for li, si, p in sorted({pair[:3] for pair in st.fetch_open}):
+            key = self._slot_key(st, li, p, STREAMS[si])
+            # shared pages fetch at THIS holder's ladder assignment
+            # (the store hint is whichever holder re-ranked last)
+            keep_fn = (None if key.seq_id == rid
+                       else lambda st=st, p=p: st.page_planes.get(p))
+            kt = key.astuple()
+            reactivate = []
+            for tier, cols in self._page_targets(key):
+                pair = (li, si, p, tier.index)
+                if pair not in st.fetch_open:
+                    continue  # waits on a queued write: counted in bulk
+                probed += 1
+                if tier.store.contains(key):
+                    tier.engine.submit(make_fetch_job(
+                        tier.store, self.stats, key,
+                        self._seq_key(tier, rid),
+                        device_kv=self.device_kv,
+                        telemetry=self.telemetry,
+                        rid=rid, keep_fn=keep_fn,
+                    ))
+                elif (tier.engine.pending(kt, JobClass.KV_WRITE)
+                      or tier.engine.pending(kt, JobClass.BACKGROUND)):
+                    # write or re-activation already queued — only
+                    # those classes restore the page; a stale queued
+                    # fetch must not suppress the re-activation
+                    self.stats["kv_fetch_deferrals"] += 1
+                    self._defer(st, pair)
+                elif self._can_reactivate(st, p, ln):
+                    reactivate.append((tier, cols))
+                    self._defer(st, pair)
+                else:
+                    # ring: the window slid over part of the page's
+                    # device rows — nothing left to re-compress, and
+                    # the page dies shortly anyway
+                    self.stats["kv_fetch_misses"] += 1
+            if reactivate:
+                self._reactivate(slot_id, key, reactivate)
+        steps.count("fetch_probed", probed)
+        steps.count("fetch_deferred", bulk)
+
+    def _sync_fetch_range(self, st: SlotState) -> None:
+        """Move the slot's fetch index to its live page range: keys of pages
+        that left it are forgotten, and each key of a page that entered it
+        starts deferred when it is not resident and a write or
+        re-activation of it is queued, else open."""
+        lo, hi = self._live_page_range(st)
+        old_lo, old_hi = st.fetch_range
+        if (lo, hi) == (old_lo, old_hi):
+            return
+        ls = self.stored_layers()
+        for p in range(old_lo, min(old_hi, lo)):
+            for li in range(ls):
+                for si in range(len(STREAMS)):
+                    for tier in self.tiers:
+                        pair = (li, si, p, tier.index)
+                        st.fetch_open.discard(pair)
+                        st.fetch_deferred.discard(pair)
+        for p in range(max(old_hi, lo), hi):
+            for li in range(ls):
+                for si, stream in enumerate(STREAMS):
                     key = self._slot_key(st, li, p, stream)
-                    # shared pages fetch at THIS holder's ladder assignment
-                    # (the store hint is whichever holder re-ranked last)
-                    keep_fn = (None if key.seq_id == rid
-                               else lambda st=st, p=p: st.page_planes.get(p))
                     kt = key.astuple()
-                    reactivate = []
-                    for tier, cols in self._page_targets(key):
-                        if tier.store.contains(key):
-                            tier.engine.submit(make_fetch_job(
-                                tier.store, self.stats, key,
-                                self._seq_key(tier, rid),
-                                device_kv=self.device_kv,
-                                telemetry=self.telemetry,
-                                rid=rid, keep_fn=keep_fn,
-                            ))
-                        elif (tier.engine.pending(kt, JobClass.KV_WRITE)
-                              or tier.engine.pending(kt, JobClass.BACKGROUND)):
-                            # write or re-activation already queued — only
-                            # those classes restore the page; a stale queued
-                            # fetch must not suppress the re-activation
-                            self.stats["kv_fetch_deferrals"] += 1
-                        elif self._can_reactivate(st, p, ln):
-                            reactivate.append((tier, cols))
-                        else:
-                            # ring: the window slid over part of the page's
-                            # device rows — nothing left to re-compress, and
-                            # the page dies shortly anyway
-                            self.stats["kv_fetch_misses"] += 1
-                    if reactivate:
-                        self._reactivate(slot_id, key, reactivate)
+                    for tier, _cols in self._page_targets(key):
+                        queued = not tier.store.contains(key) and (
+                            tier.engine.pending(kt, JobClass.KV_WRITE)
+                            or tier.engine.pending(kt, JobClass.BACKGROUND))
+                        (st.fetch_deferred if queued else st.fetch_open).add(
+                            (li, si, p, tier.index))
+        st.fetch_range = (lo, hi)
+
+    @staticmethod
+    def _defer(st: SlotState, pair: tuple) -> None:
+        st.fetch_open.discard(pair)
+        st.fetch_deferred.add(pair)
+
+    @staticmethod
+    def _reopen(st: SlotState, pairs) -> None:
+        for pair in pairs:
+            st.fetch_deferred.discard(pair)
+            st.fetch_open.add(pair)
+
+    def _note_put(self, tier: MemTier, key: PageKey, writer: SlotState) -> None:
+        """A serviced write or re-activation put ``key`` on ``tier``: every
+        slot whose index counts that key as deferred probes it again.  A
+        request-keyed page belongs to its writer alone; a shared page to
+        every slot whose key at that place is the same."""
+        pair = (key.layer, STREAMS.index(key.stream), key.page_idx,
+                tier.index)
+        holders = (self._slots.values() if is_prefix_seq(key.seq_id)
+                   else (writer,))
+        for st in holders:
+            if pair in st.fetch_deferred and self._slot_key(
+                    st, key.layer, key.page_idx, key.stream) == key:
+                self._reopen(st, (pair,))
 
     def _reactivate(self, slot_id: int, key: PageKey, targets) -> None:
         """An evicted page is needed again: queue a background re-compress
@@ -1019,10 +1113,11 @@ class KVBackend(abc.ABC):
         for tier, cols in targets:
             part = page if cols is None else page[:, cols]
 
-            def fn(store=tier.store, key=key, part=part, valid=valid, st=st):
-                store.put_page(key, part,
-                               planes=st.page_planes.get(key.page_idx),
-                               valid_tokens=valid)
+            def fn(tier=tier, key=key, part=part, valid=valid, st=st):
+                tier.store.put_page(key, part,
+                                    planes=st.page_planes.get(key.page_idx),
+                                    valid_tokens=valid)
+                self._note_put(tier, key, st)
                 self.stats["kv_reactivations"] += 1
 
             tier.engine.submit(Job(JobClass.BACKGROUND, part[:valid].nbytes,

@@ -32,6 +32,10 @@ Counters (zero unless the step did the work):
   that moved them;
 * ``fill_pages``: decode pages the batched page-fill copy served, one per
   slot whose page filled;
+* ``fetch_probed`` / ``fetch_deferred``: (page key, tier) pairs of the
+  decoding slots' live pages that decode fetch accounting classified one
+  by one (resident, evicted or dead), and those it counted in bulk as
+  waiting on a queued write; their sum is every live pair;
 * ``compiles`` / ``compile_s``: XLA compile requests during the step and
   their seconds, from JAX's ``backend_compile_duration`` event (a load
   from the persistent compile cache is a request too);
@@ -57,8 +61,8 @@ import jax
 PHASES = ("admit", "prefill_dispatch", "decode_dispatch", "kv_flush",
           "decode_wait", "decode_commit", "engine_tick", "retire")
 COUNTERS = ("decoded", "memctl_jobs", "memctl_queue", "d2h_bytes",
-            "d2h_copies", "fill_pages", "compiles", "compile_s",
-            "moe_held_picks")
+            "d2h_copies", "fill_pages", "fetch_probed", "fetch_deferred",
+            "compiles", "compile_s", "moe_held_picks")
 PREFIX = "serve."
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
